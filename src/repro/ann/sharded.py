@@ -63,7 +63,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.ann.partition import soar_cost
 from repro.core import hashing
@@ -253,14 +252,14 @@ def make_query_step(mesh, cell: GusCellConfig):
             fin_rows = jnp.take_along_axis(all_rows, fin_pos, axis=-1)
         return fin_rows, -fin_scores                          # ids, distances
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_query, mesh=mesh,
         in_specs=(P(), P(), P(),
                   ispec["centroids"], ispec["books"], ispec["members_idx"],
                   ispec["members_val"], ispec["codes"], ispec["row_ids"],
                   ispec["valid"], ispec["counts"]),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def step(q_idx, q_val, q_sketch, state):
         return fn(q_idx, q_val, q_sketch, state["centroids"], state["books"],
@@ -351,7 +350,7 @@ def make_mutate_step(mesh, cell: GusCellConfig, salt: int = 3):
         return (m_idx, m_val, codes, row_ids, valid, counts,
                 route_part.reshape(b, nc), route_pos.reshape(b, nc))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_mutate, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(),
                   ispec["centroids"], ispec["members_idx"],
@@ -360,7 +359,7 @@ def make_mutate_step(mesh, cell: GusCellConfig, salt: int = 3):
         out_specs=(ispec["members_idx"], ispec["members_val"], ispec["codes"],
                    ispec["row_ids"], ispec["valid"], ispec["counts"],
                    P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def step(ids, new_idx, new_val, new_sketch, new_codes, state,
              new_codes2=None):
@@ -399,11 +398,11 @@ def make_delete_step(mesh, cell: GusCellConfig):
         row = jnp.where(ok, local, c_loc)                     # OOB drops
         return valid.at[row, poss].set(False, mode="drop")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_clear, mesh=mesh,
         in_specs=(P(), P(), ispec["valid"]),
         out_specs=ispec["valid"],
-        check_rep=False)
+        check_vma=False)
 
     def step(parts, poss, state):
         return {**state, "valid": fn(parts, poss, state["valid"])}
@@ -450,14 +449,14 @@ def make_compact_step(mesh, cell: GusCellConfig):
                 g3(codes, 0).astype(jnp.uint8), g2(row_ids, PAD_ID),
                 new_valid, n_live, new_pos)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_compact, mesh=mesh,
         in_specs=(ispec["members_idx"], ispec["members_val"], ispec["codes"],
                   ispec["row_ids"], ispec["valid"]),
         out_specs=(ispec["members_idx"], ispec["members_val"], ispec["codes"],
                    ispec["row_ids"], ispec["valid"], ispec["counts"],
                    ispec["valid"]),
-        check_rep=False)
+        check_vma=False)
 
     def step(state):
         m_idx, m_val, codes, row_ids, valid, counts, new_pos = fn(

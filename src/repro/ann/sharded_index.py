@@ -89,7 +89,7 @@ from repro.ann.sparse import count_sketch
 from repro.core import hashing
 from repro.core.maintenance import MaintenanceConfig, resolve_legacy
 from repro.core.types import PAD_INDEX, SparseBatch
-from repro.launch.mesh import make_gus_mesh, mesh_context
+from repro.launch.mesh import make_gus_mesh
 from repro.obs import Telemetry
 from repro.utils import pow2_pad
 
@@ -364,7 +364,7 @@ class ShardedGusIndex:
             "valid": jnp.zeros((c, s), bool),
             "counts": jnp.zeros((c,), jnp.int32),
         }
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.state = {k: jax.device_put(
                 v, NamedSharding(self.mesh, specs[k]))
                 for k, v in init.items()}
@@ -497,7 +497,7 @@ class ShardedGusIndex:
                 b_codes2 = np.zeros((cfg.mutate_batch, cfg.pq_m), np.uint8)
                 b_codes2[:n_c] = codes2[sel]
                 b_codes2 = jnp.asarray(b_codes2)
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.state, (r_part, r_pos) = self._mutate(
                     jnp.asarray(ids_u), jnp.asarray(b_idx),
                     jnp.asarray(b_val), jnp.asarray(b_sk),
@@ -563,7 +563,7 @@ class ShardedGusIndex:
             poss = np.zeros((bm,), np.int32)
             parts[:len(chunk)] = np.asarray(chunk, np.int64) // self.slab
             poss[:len(chunk)] = np.asarray(chunk, np.int64) % self.slab
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.state = self._tombstone(
                     jnp.asarray(parts), jnp.asarray(poss), self.state)
 
@@ -607,7 +607,7 @@ class ShardedGusIndex:
         """
         assert self.trained, "build() the index before compacting it"
         t0 = time.perf_counter()
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             new_state, new_pos = self._compact_step(self.state)
         new_pos = np.asarray(new_pos)
         occupied = int(np.minimum(self._cursor, self.slab).sum())
@@ -674,7 +674,7 @@ class ShardedGusIndex:
         self.slab = old_s * 2
         cell = self._cell()
         specs = index_specs(cell, self.mesh)
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             for key, pad in pads.items():
                 st[key] = jax.device_put(
                     np.concatenate([np.asarray(st[key]), pad], axis=1),
@@ -900,7 +900,7 @@ class ShardedGusIndex:
             q_sk = np.zeros((padded, cfg.d_proj), np.float32)
             q_sk[:n_c] = sk[sel]
             step = self._query_step(padded, k_eff)
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 rows, dists = step(jnp.asarray(q_idx), jnp.asarray(q_val),
                                    jnp.asarray(q_sk), self.state)
             rows = np.asarray(rows)[:n_c]

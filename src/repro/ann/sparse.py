@@ -31,10 +31,17 @@ def sparse_dot_one_many(qi, qv, db_idx, db_val) -> jax.Array:
     """One query row vs a database block.
 
     qi,qv: [Kq]; db_idx,db_val: [N, Kd] -> scores f32 [N].
+
+    Query nonzeros accumulate in order (j = 0..Kq-1), each one's matches
+    summed first. A row's indices are distinct, so each term holds at most
+    one product and the result does not depend on how a backend orders a
+    reduction; ``kernels/sparse_dot`` sums the same way, bitwise.
     """
-    eq = (qi[None, :, None] == db_idx[:, None, :]) & (qi[None, :, None] != PAD_INDEX)
-    prod = qv[None, :, None] * db_val[:, None, :]
-    return jnp.sum(jnp.where(eq, prod, 0.0), axis=(1, 2))
+    out = jnp.zeros(db_idx.shape[:1], jnp.result_type(qv, db_val))
+    for j in range(qi.shape[0]):
+        hit = (db_idx == qi[j]) & (qi[j] != PAD_INDEX)
+        out = out + jnp.sum(jnp.where(hit, qv[j] * db_val, 0.0), axis=-1)
+    return out
 
 
 def sparse_dot_many_many(q: SparseBatch, db: SparseBatch) -> jax.Array:

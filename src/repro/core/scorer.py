@@ -25,6 +25,7 @@ from typing import Mapping
 
 import jax
 import jax.numpy as jnp
+from jax.lax import Precision
 
 from repro.core.types import FeatureSpec, PAD_ITEM
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
@@ -79,10 +80,13 @@ def scorer_init(key: jax.Array, spec: FeatureSpec,
 
 
 def scorer_logits(params: dict, feats: jax.Array) -> jax.Array:
+    # f32 matmuls at HIGHEST: a TPU's default single bf16 pass would make
+    # the jnp, kernel and ref backends disagree in the third digit
     h = feats
     n_layers = len(params) // 2
     for i in range(n_layers):
-        h = h @ params[f"w{i}"] + params[f"b{i}"]
+        h = jnp.dot(h, params[f"w{i}"],
+                    precision=Precision.HIGHEST) + params[f"b{i}"]
         if i < n_layers - 1:
             h = jnp.tanh(h)
     return h[..., 0]

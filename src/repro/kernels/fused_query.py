@@ -3,11 +3,14 @@
 The serving shortlist path used to run three separately-jitted ops with HBM
 round-trips between them: ``pq_score_batched`` (LUT scoring), an
 ``argsort(id)``-based SOAR dedup, and ``topk_select``.  This kernel fuses
-all three: one program per query row keeps the candidate slab resident in
-VMEM, accumulates the PQ lookup scores on the MXU (ordered per-subspace
-accumulation, see below), masks invalid rows, then runs k rounds of
-(max, lowest-index argmax, mask-out) selection with the SOAR duplicate
-check done **in-register** against the ids already selected.
+all three: each grid step takes 8 query rows x ``BLOCK_N`` candidates,
+accumulates their PQ lookup scores on the MXU (``pq_score.score_block``,
+ordered per-subspace accumulation, see below), masks invalid rows, and
+merges them into a running top-k held in VMEM (``topk_select.
+select_topk``: k rounds of max, lowest-index argmax, retire). The point
+id and validity of every kept candidate ride along, and after the last
+block the SOAR duplicate check runs **in-register** over the final
+shortlist.
 
 Result contract (pinned bitwise by tests/test_kernels_fused.py):
 
@@ -30,8 +33,9 @@ Ordered accumulation: f32 addition is not associative, so the kernel, the
 single-jit XLA twin (``fused_query_xla``) and the oracle
 (``ref.fused_query_ref``) all accumulate subspaces left-to-right
 (``acc += gather(lut[m])`` for m = 0..M-1).  The one-hot matmul form used
-on the MXU adds exact zeros to the gathered value, which is bitwise
-neutral, so kernel == twin == oracle bitwise.  LUT and bias values must be
+on the MXU (over an exact bf16 split of the LUT, ``pq_score.split_bf16``)
+adds exact zeros to the gathered value, which is bitwise neutral, so
+kernel == twin == oracle bitwise.  LUT and bias values must be
 finite (0 * inf would poison the one-hot matmul).
 
 The int8 variant quantises the LUT per (query, subspace) with a symmetric
@@ -49,91 +53,70 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# plain float so kernel bodies don't capture a traced constant
-NEG_INF = float("-inf")
+from repro.kernels.pq_score import (BLOCK_B, compiler_params, pad_axis,
+                                    round_up, score_block)
+from repro.kernels.topk_select import (NEG_INF, RETIRED, block_index,
+                                       block_n_for, select_topk)
 
 
 # ---------------------------------------------------------------------------
-# shared kernel pieces
+# kernel
 
 
-def _score_rows_f32(lut, codes, n_centers: int):
-    """Ordered LUT accumulation. lut [M, C] f32; codes [N, M] u8 -> [N]."""
-    acc = jnp.zeros((codes.shape[0],), jnp.float32)
-    for mi in range(lut.shape[0]):      # static unroll, fixed l-to-r order
-        onehot = (codes[:, mi].astype(jnp.int32)[:, None]
-                  == jnp.arange(n_centers, dtype=jnp.int32)[None, :])
-        acc += onehot.astype(jnp.float32) @ lut[mi]          # MXU row
-    return acc
+def _dedup_cut(vals, ids, ok):
+    """Dedup-after-cut over the final shortlist: slot i -> -inf iff some
+    earlier slot j < i holds the same point id with both slots valid."""
+    rows, k = vals.shape
+    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
+
+    def body(i, dup):
+        slot = slot_iota == i
+        id_i = jnp.sum(jnp.where(slot, ids, 0), axis=1, keepdims=True)
+        ok_i = jnp.sum(jnp.where(slot, ok, 0), axis=1, keepdims=True)
+        same = (slot_iota < i) & (ids == id_i) & (ok != 0)
+        hit = jnp.max(jnp.where(same, 1, 0), axis=1, keepdims=True)
+        return jnp.where(slot & (hit > 0) & (ok_i != 0), 1, dup)
+
+    dup = jax.lax.fori_loop(0, k, body, jnp.zeros((rows, k), jnp.int32))
+    return jnp.where(dup != 0, NEG_INF, vals)
 
 
-def _score_rows_int8(qlut, scale, codes, n_centers: int):
-    """Quantised variant: qlut i8 [M, C]; scale f32 [M]; codes [N, M].
+def _fused_kernel(*refs, n_centers: int, k: int, block_n: int,
+                  quantized: bool):
+    if quantized:
+        (lut_ref, scale_ref, codes_ref, ids_ref, valid_ref, bias_ref,
+         vals_ref, idxs_ref, sel_ids, sel_ok) = refs
+    else:
+        (lut_ref, codes_ref, ids_ref, valid_ref, bias_ref,
+         vals_ref, idxs_ref, sel_ids, sel_ok) = refs
+        scale_ref = None
+    j = pl.program_id(1)
 
-    Dequantise-then-score: the scale multiply happens on the LUT table,
-    never in the accumulation chain, so XLA cannot contract it into an
-    FMA and drift a ulp from the eager oracle (gather-of-mul is bitwise
-    mul-of-gather)."""
-    deq = qlut.astype(jnp.float32) * scale[:, None]
-    return _score_rows_f32(deq, codes, n_centers)
+    @pl.when(j == 0)
+    def _():
+        vals_ref[...] = jnp.full(vals_ref.shape, NEG_INF, jnp.float32)
+        idxs_ref[...] = jnp.full(idxs_ref.shape, RETIRED, jnp.int32)
+        sel_ids[...] = jnp.zeros(sel_ids.shape, jnp.int32)
+        sel_ok[...] = jnp.zeros(sel_ok.shape, jnp.int32)
 
-
-def _select_dedup(scores, ids, valid, k: int):
-    """k rounds of (max, lowest-index argmax, mask-out) with in-register
-    SOAR dedup: an ``alive`` mask (not the mask-to--inf trick) so that
-    legitimate -inf scores — tombstones, padding — still select distinct
-    indices exactly like ``lax.top_k``."""
-    n = scores.shape[0]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    iota_k = jnp.arange(k, dtype=jnp.int32)
-
-    def body(i, carry):
-        alive, vals, idxs, sel_ids, sel_ok = carry
-        masked = jnp.where(alive, scores, NEG_INF)
-        best = jnp.max(masked)
-        bi = jnp.min(jnp.where(alive & (masked == best), iota, n))
-        bi = bi.astype(jnp.int32)
-        hit = iota == bi
-        # O(N) reductions instead of a gather: the selected id + validity
-        id_b = jnp.sum(jnp.where(hit, ids, 0)).astype(jnp.int32)
-        ok_b = jnp.any(hit & valid)
-        dup = jnp.any((sel_ids == id_b) & sel_ok & (iota_k < i)) & ok_b
-        vals = jnp.where(iota_k == i, jnp.where(dup, NEG_INF, best), vals)
-        idxs = jnp.where(iota_k == i, bi, idxs)
-        sel_ids = jnp.where(iota_k == i, id_b, sel_ids)
-        sel_ok = jnp.where(iota_k == i, ok_b, sel_ok)
-        return alive & (iota != bi), vals, idxs, sel_ids, sel_ok
-
-    init = (jnp.ones((n,), jnp.bool_),
-            jnp.full((k,), NEG_INF, jnp.float32),
-            jnp.zeros((k,), jnp.int32),
-            jnp.full((k,), -1, jnp.int32),
-            jnp.zeros((k,), jnp.bool_))
-    _, vals, idxs, _, _ = jax.lax.fori_loop(0, k, body, init)
-    return vals, idxs
-
-
-def _fused_kernel(lut_ref, codes_ref, ids_ref, valid_ref, bias_ref,
-                  vals_ref, idxs_ref, *, n_centers: int, k: int):
-    valid = valid_ref[...] != 0
-    acc = _score_rows_f32(lut_ref[...], codes_ref[...], n_centers)
-    scores = jnp.where(valid, acc + bias_ref[...], NEG_INF)
-    vals, idxs = _select_dedup(scores, ids_ref[...], valid, k)
+    valid = valid_ref[...]
+    acc = score_block(lut_ref, codes_ref, n_centers=n_centers,
+                      shared_codes=False, scale_ref=scale_ref)
+    scores = jnp.where(valid != 0, acc + bias_ref[...], NEG_INF)
+    vals, idxs, (ids, ok) = select_topk(
+        [(vals_ref[...], idxs_ref[...], (sel_ids[...], sel_ok[...])),
+         (scores, block_index(scores.shape, block_n),
+          (ids_ref[...], valid))], k)
     vals_ref[...] = vals
     idxs_ref[...] = idxs
+    sel_ids[...] = ids
+    sel_ok[...] = ok
 
-
-def _fused_kernel_int8(qlut_ref, scale_ref, codes_ref, ids_ref, valid_ref,
-                       bias_ref, vals_ref, idxs_ref, *, n_centers: int,
-                       k: int):
-    valid = valid_ref[...] != 0
-    acc = _score_rows_int8(qlut_ref[...], scale_ref[...], codes_ref[...],
-                           n_centers)
-    scores = jnp.where(valid, acc + bias_ref[...], NEG_INF)
-    vals, idxs = _select_dedup(scores, ids_ref[...], valid, k)
-    vals_ref[...] = vals
-    idxs_ref[...] = idxs
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        vals_ref[...] = _dedup_cut(vals, ids, ok)
 
 
 # ---------------------------------------------------------------------------
@@ -156,16 +139,46 @@ def quantize_lut(lut: jax.Array):
 # pallas_call wrappers
 
 
-def _row_spec(nn):
-    return pl.BlockSpec((None, nn), lambda qb: (qb, 0))
+def _fused_call(lut, scale, codes, ids, valid, bias, k: int,
+                interpret: bool):
+    """lut [B, M, C] (i8 when ``scale`` f32 [B, M] is given); codes u8
+    [B, N, M]; ids i32, valid i32, bias f32 [B, N]. B pads to 8 and N to
+    the block; padded candidates sit after the real ones (valid=0, id=-1)
+    so the lowest-index tie-break never prefers them while k <= n."""
+    b, m, c = lut.shape
+    n = codes.shape[1]
+    assert k <= n, f"k={k} exceeds candidate count n={n}"
+    bn = block_n_for(n, k)
+    bp, np_ = round_up(b, BLOCK_B), round_up(n, bn)
 
+    def rows(x, value=0):
+        return pad_axis(pad_axis(x, 0, bp), 1, np_, value)
 
-def _pad_rows(codes, ids, valid, bias, n_pad: int):
-    codes = jnp.pad(codes, ((0, 0), (0, n_pad), (0, 0)))
-    ids = jnp.pad(ids, ((0, 0), (0, n_pad)), constant_values=-1)
-    valid = jnp.pad(valid, ((0, 0), (0, n_pad)))
-    bias = jnp.pad(bias, ((0, 0), (0, n_pad)))
-    return codes, ids, valid, bias
+    args = [pad_axis(lut, 0, bp).transpose(1, 0, 2)]          # [M, Bp, C]
+    specs = [pl.BlockSpec((m, BLOCK_B, c), lambda i, j: (0, i, 0))]
+    if scale is not None:
+        args.append(pad_axis(scale, 0, bp, 1.0).T[:, :, None])  # [M, Bp, 1]
+        specs.append(pl.BlockSpec((m, BLOCK_B, 1), lambda i, j: (0, i, 0)))
+    row_spec = pl.BlockSpec((BLOCK_B, bn), lambda i, j: (i, j))
+    args += [rows(codes).transpose(0, 2, 1),                   # [Bp, M, Np]
+             rows(ids, -1), rows(valid), rows(bias)]
+    specs += [pl.BlockSpec((BLOCK_B, m, bn), lambda i, j: (i, 0, j)),
+              row_spec, row_spec, row_spec]
+    out_spec = pl.BlockSpec((BLOCK_B, k), lambda i, j: (i, 0))
+    vals, idxs = pl.pallas_call(
+        functools.partial(_fused_kernel, n_centers=c, k=k, block_n=bn,
+                          quantized=scale is not None),
+        grid=(bp // BLOCK_B, np_ // bn),
+        in_specs=specs,
+        out_specs=(out_spec, out_spec),
+        out_shape=(jax.ShapeDtypeStruct((bp, k), jnp.float32),
+                   jax.ShapeDtypeStruct((bp, k), jnp.int32)),
+        scratch_shapes=[pltpu.VMEM((BLOCK_B, k), jnp.int32),
+                        pltpu.VMEM((BLOCK_B, k), jnp.int32)],
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(*args)
+    return vals[:b], idxs[:b]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -173,58 +186,14 @@ def fused_query_kernel(lut, codes, ids, valid, bias, k: int, *,
                        interpret: bool = False):
     """Single pallas_call: lut f32 [B,M,C]; codes u8 [B,N,M]; ids i32 [B,N];
     valid i32 [B,N]; bias f32 [B,N] -> (vals f32 [B,k], idxs i32 [B,k])."""
-    b, m, c = lut.shape
-    n = codes.shape[1]
-    assert k <= n, f"k={k} exceeds candidate count n={n}"
-    # pad N to the lane grain only when lowering through Mosaic; padding
-    # sits after the real rows (valid=0, id=-1) so the lowest-index
-    # tie-break can never prefer a padded slot while k <= n
-    n_pad = 0 if interpret else -n % 128
-    if n_pad:
-        codes, ids, valid, bias = _pad_rows(codes, ids, valid, bias, n_pad)
-    nn = n + n_pad
-    return pl.pallas_call(
-        functools.partial(_fused_kernel, n_centers=c, k=k),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((None, m, c), lambda qb: (qb, 0, 0)),
-            pl.BlockSpec((None, nn, m), lambda qb: (qb, 0, 0)),
-            _row_spec(nn), _row_spec(nn), _row_spec(nn),
-        ],
-        out_specs=(pl.BlockSpec((None, k), lambda qb: (qb, 0)),
-                   pl.BlockSpec((None, k), lambda qb: (qb, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, k), jnp.int32)),
-        interpret=interpret,
-    )(lut, codes, ids, valid, bias)
+    return _fused_call(lut, None, codes, ids, valid, bias, k, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def fused_query_kernel_int8(qlut, scale, codes, ids, valid, bias, k: int, *,
                             interpret: bool = False):
     """Quantised variant: qlut i8 [B,M,C]; scale f32 [B,M]; rest as above."""
-    b, m, c = qlut.shape
-    n = codes.shape[1]
-    assert k <= n, f"k={k} exceeds candidate count n={n}"
-    n_pad = 0 if interpret else -n % 128
-    if n_pad:
-        codes, ids, valid, bias = _pad_rows(codes, ids, valid, bias, n_pad)
-    nn = n + n_pad
-    return pl.pallas_call(
-        functools.partial(_fused_kernel_int8, n_centers=c, k=k),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((None, m, c), lambda qb: (qb, 0, 0)),
-            pl.BlockSpec((None, m), lambda qb: (qb, 0)),
-            pl.BlockSpec((None, nn, m), lambda qb: (qb, 0, 0)),
-            _row_spec(nn), _row_spec(nn), _row_spec(nn),
-        ],
-        out_specs=(pl.BlockSpec((None, k), lambda qb: (qb, 0)),
-                   pl.BlockSpec((None, k), lambda qb: (qb, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, k), jnp.int32)),
-        interpret=interpret,
-    )(qlut, scale, codes, ids, valid, bias)
+    return _fused_call(qlut, scale, codes, ids, valid, bias, k, interpret)
 
 
 # ---------------------------------------------------------------------------

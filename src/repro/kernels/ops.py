@@ -1,14 +1,15 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On this CPU container the kernels execute in ``interpret=True`` mode (the
-kernel body runs op-by-op in Python/XLA-CPU, validating semantics); on a
-real TPU runtime set ``REPRO_PALLAS_COMPILE=1`` (or pass interpret=False)
-to lower through Mosaic. The wrappers also apply hardware-alignment
-padding so callers never need to know the lane/sublane grain.
+The platform picks the path, in one place (``on_tpu``): on a TPU every
+entry point lowers its Pallas kernel through Mosaic, and the fused
+shortlist ops always run the kernel. On any other backend (the CPU test
+runs) the kernels execute in interpret mode, and ``use_kernel=None``
+routes the shortlist ops to their bitwise-identical single-jit XLA twins.
+Explicit ``interpret=`` / ``use_kernel=`` arguments exist for the parity
+tests only. The kernel wrappers pad to the hardware grain (8 query rows,
+128 lanes) themselves, so callers never need to know it.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,48 +20,49 @@ from repro.kernels import scorer_mlp as _mlp
 from repro.kernels import sparse_dot as _sd
 from repro.kernels import topk_select as _tk
 
-# interpret unless explicitly compiling for TPU
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
 quantize_lut = _fq.quantize_lut
 
 
-def pq_score(lut: jax.Array, codes: jax.Array, *, block_n: int = 256,
+def on_tpu() -> bool:
+    """Whether the default backend compiles Pallas TPU kernels."""
+    return jax.default_backend() == "tpu"
+
+
+def _interpret(interpret: bool | None) -> bool:
+    return not on_tpu() if interpret is None else interpret
+
+
+def pq_score(lut: jax.Array, codes: jax.Array, *,
              interpret: bool | None = None) -> jax.Array:
     """LUT scoring: lut f32 [B, M, C]; codes u8 [N, M] -> f32 [B, N]."""
-    return _pq.pq_score(lut, codes, block_n=block_n,
-                        interpret=INTERPRET if interpret is None else interpret)
+    return _pq.pq_score(lut, codes, interpret=_interpret(interpret))
 
 
-def pq_score_batched(lut, codes, *, block_n: int = 256,
-                     interpret: bool | None = None) -> jax.Array:
+def pq_score_batched(lut, codes, *, interpret: bool | None = None
+                     ) -> jax.Array:
     """Per-query slabs: lut f32 [B, M, C]; codes u8 [B, N, M] -> [B, N]."""
-    return _pq.pq_score_batched(
-        lut, codes, block_n=block_n,
-        interpret=INTERPRET if interpret is None else interpret)
+    return _pq.pq_score_batched(lut, codes, interpret=_interpret(interpret))
 
 
-def sparse_dot(q_idx, q_val, db_idx, db_val, *, block_n: int = 128,
+def sparse_dot(q_idx, q_val, db_idx, db_val, *,
                interpret: bool | None = None) -> jax.Array:
     """Exact sparse-sparse scores: q [B,Kq] vs db [N,Kd] -> f32 [B, N]."""
-    return _sd.sparse_dot(q_idx, q_val, db_idx, db_val, block_n=block_n,
-                          interpret=INTERPRET if interpret is None else interpret)
+    return _sd.sparse_dot(q_idx, q_val, db_idx, db_val,
+                          interpret=_interpret(interpret))
 
 
-def sparse_dot_batched(q_idx, q_val, db_idx, db_val, *, block_n: int = 128,
+def sparse_dot_batched(q_idx, q_val, db_idx, db_val, *,
                        interpret: bool | None = None) -> jax.Array:
     """Shortlist rescoring: q [B,Kq] vs db [B,R,Kd] -> f32 [B, R]."""
-    return _sd.sparse_dot_batched(
-        q_idx, q_val, db_idx, db_val, block_n=block_n,
-        interpret=INTERPRET if interpret is None else interpret)
+    return _sd.sparse_dot_batched(q_idx, q_val, db_idx, db_val,
+                                  interpret=_interpret(interpret))
 
 
 def topk_select(scores: jax.Array, k: int, *, interpret: bool | None = None):
     """Row-wise top-k (vals, idxs). Kernel path for k <= 64, else lax."""
     if k > 64:
         return jax.lax.top_k(scores, k)
-    return _tk.topk_select(
-        scores, k, interpret=INTERPRET if interpret is None else interpret)
+    return _tk.topk_select(scores, k, interpret=_interpret(interpret))
 
 
 def pq_scores(lut, codes, *, quantized: bool = False,
@@ -69,21 +71,19 @@ def pq_scores(lut, codes, *, quantized: bool = False,
     """Raw shortlist scores with the fused-path ordering contract:
     lut f32 [B, M, C]; codes u8 [B, N, M] -> f32 [B, N].
 
-    ``use_kernel=None`` routes through Pallas only when the process is
-    compiling kernels (REPRO_PALLAS_COMPILE=1); otherwise the single-jit
-    XLA twin runs with bitwise-identical results.  The quantised variant
+    ``use_kernel=None`` runs the Pallas kernel on a TPU and the single-jit
+    XLA twin elsewhere; both are bitwise identical. The quantised variant
     always scores through the XLA twin (the int8 pallas path only exists
     fused, inside pq_score_dedup_topk).
     """
     if use_kernel is None:
-        use_kernel = not INTERPRET
+        use_kernel = on_tpu()
     if quantized:
         qlut, scale = _fq.quantize_lut(lut)
         return _pq_scores_seq_int8_jit(qlut, scale, codes)
     if use_kernel:
-        return _pq.pq_score_batched(
-            lut, codes,
-            interpret=INTERPRET if interpret is None else interpret)
+        return _pq.pq_score_batched(lut, codes,
+                                    interpret=_interpret(interpret))
     return _pq_scores_seq_jit(lut, codes)
 
 
@@ -117,13 +117,13 @@ def pq_score_dedup_topk(lut, codes, ids, k: int, *, valid=None, bias=None,
     -> (vals f32 [B, k], idxs i32 [B, k]).  See kernels/fused_query.py for
     the full result contract.
 
-    ``use_kernel=None`` -> pallas_call only under REPRO_PALLAS_COMPILE=1,
-    else the bitwise-identical single-jit XLA twin (the CPU production
-    route).  ``use_kernel=True`` forces the pallas_call (interpreted per
-    ``interpret``/INTERPRET) — what the parity tests exercise.
+    ``use_kernel=None`` runs the pallas_call on a TPU and the
+    bitwise-identical single-jit XLA twin elsewhere. ``use_kernel=True``
+    forces the pallas_call (interpreted off a TPU) — what the parity tests
+    exercise.
     """
     if use_kernel is None:
-        use_kernel = not INTERPRET
+        use_kernel = on_tpu()
     if not use_kernel:
         # normalization (astype, default masks) happens inside the jit —
         # eager per-call conversions here cost more than the op itself
@@ -135,7 +135,7 @@ def pq_score_dedup_topk(lut, codes, ids, k: int, *, valid=None, bias=None,
              else jnp.asarray(valid).astype(jnp.bool_))
     bias = (jnp.zeros((b, n), jnp.float32) if bias is None
             else jnp.asarray(bias).astype(jnp.float32))
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     valid_i = valid.astype(jnp.int32)
     if quantized:
         qlut, scale = _fq.quantize_lut(lut)
@@ -148,9 +148,9 @@ def pq_score_dedup_topk(lut, codes, ids, k: int, *, valid=None, bias=None,
 def scorer_mlp(feats, params: dict, *, interpret: bool | None = None):
     """Fused paper-scorer: feats [B, F] + core.scorer params -> f32 [B].
 
-    Pads hidden dims to the 128-lane grain once per params object.
+    Pads hidden dims to the 128-lane grain (8 when interpreted).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     w0, b0 = params["w0"], params["b0"]
     w1, b1 = params["w1"], params["b1"]
     w2, b2 = params["w2"], params["b2"]

@@ -34,10 +34,15 @@ def topk_ref(scores: jax.Array, k: int):
 
 
 def scorer_mlp_ref(feats, w0, b0, w1, b1, w2, b2) -> jax.Array:
-    """Fused 2-hidden-layer tanh MLP + sigmoid head. feats [B,F] -> [B]."""
-    h = jnp.tanh(feats.astype(jnp.float32) @ w0.astype(jnp.float32) + b0)
-    h = jnp.tanh(h @ w1.astype(jnp.float32) + b1)
-    return jax.nn.sigmoid((h @ w2.astype(jnp.float32) + b2)[..., 0])
+    """Fused 2-hidden-layer tanh MLP + sigmoid head. feats [B,F] -> [B].
+    f32 matmuls at HIGHEST precision, on every backend."""
+    def dot(a, w):
+        return jnp.dot(a, w.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+
+    h = jnp.tanh(dot(feats.astype(jnp.float32), w0) + b0)
+    h = jnp.tanh(dot(h, w1) + b1)
+    return jax.nn.sigmoid((dot(h, w2) + b2)[..., 0])
 
 
 def pq_score_seq_ref(lut: jax.Array, codes: jax.Array) -> jax.Array:

@@ -18,7 +18,7 @@ from repro.configs.base import SHAPES, applicable          # noqa: E402
 from repro.configs.registry import ARCHS, get_config        # noqa: E402
 from repro.launch import sharding as shp                    # noqa: E402
 from repro.launch.mesh import (make_gus_mesh,               # noqa: E402
-                               make_production_mesh, mesh_context)
+                               make_production_mesh)
 from repro.models.model import (cache_specs,                # noqa: E402
                                 input_specs, params_specs)
 from repro.serve.serve_step import make_decode_step, make_prefill_step  # noqa: E402
@@ -124,8 +124,6 @@ def build_cell(cfg, shape, mesh):
 def analyze(compiled) -> dict:
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per program
-        ca = ca[0] if ca else {}
     coll = collective_stats(compiled.as_text())
     return {
         "memory": {
@@ -172,7 +170,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         sp_axis="model", model_axis_size=16)
     n_dev = int(np.prod(list(mesh.devices.shape)))
     rec["devices"] = n_dev
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if not probes_only:
             t0 = time.time()
             lowered = build_cell(cfg, shape, mesh)()
@@ -184,8 +182,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             if verbose:
                 print(compiled.memory_analysis())
                 ca = compiled.cost_analysis() or {}
-                if isinstance(ca, (list, tuple)):
-                    ca = ca[0] if ca else {}
                 print({k: v for k, v in ca.items()
                        if k in ("flops", "bytes accessed")})
 
@@ -267,7 +263,7 @@ def run_gus_cell(multi_pod: bool, out_dir: str = "results/dryrun",
         kind = f"{kind}_{tag}"
     rec = {"arch": "dynamic-gus", "shape": cell.name, "mesh": mesh_name,
            "kind": kind}
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         state_sds = index_shapes(cell)
         if op == "mutate":
             step = make_mutate_step(mesh, cell)

@@ -1,7 +1,7 @@
 """Production mesh construction.
 
 A function (not a module-level constant) so importing this module never
-touches jax device state — the dry-run must set XLA_FLAGS before any jax
+touches jax device state — CPU runs must set XLA_FLAGS before any jax
 initialization.
 
 Axis semantics:
@@ -9,53 +9,30 @@ Axis semantics:
   data  — intra-pod DP/FSDP axis (and index-shard axis for GUS)
   model — TP/EP axis
 
-The helpers below also paper over the jax mesh-API drift: newer jax wants
-``axis_types=(AxisType.Auto, ...)`` and activates a mesh via
-``jax.set_mesh``; older releases (like the 0.4.x pinned here) predate both.
-``make_*_mesh`` and ``mesh_context`` give every caller one spelling that
-works on either, so the same GUS programs lower for the pod cells and run
-unmodified on a 2-4 device CPU mesh.
+Every mesh is built with ``Auto`` axis types, and callers activate one
+with ``jax.set_mesh``, so the same GUS programs lower for the pod cells,
+run on one or four TPU chips, and run on a 2-4 device CPU mesh.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across API generations (axis_types when supported)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes, devices=devices,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes, devices=devices)
-
-
-def mesh_context(mesh):
-    """Activate ``mesh`` for the enclosed computation.
-
-    ``jax.set_mesh(mesh)`` on new jax; on old releases explicit-mesh
-    shard_map needs no ambient mesh, so this is a no-op context.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return contextlib.nullcontext(mesh)
+def _auto_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for CPU tests (requires >= prod(shape) host devices)."""
-    return _make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_gus_mesh(n_shards: int, *, two_level: bool = False, pod: int = 0):
@@ -77,20 +54,22 @@ def make_gus_mesh(n_shards: int, *, two_level: bool = False, pod: int = 0):
     have = len(jax.devices())
     need = (pod + 1) * n_shards
     if need > have:
+        platform = jax.devices()[0].platform
+        hint = (f"set XLA_FLAGS=--xla_force_host_platform_device_count="
+                f"{need} before jax initializes" if platform == "cpu" else
+                f"run on a host with at least {need} {platform} devices")
         raise ValueError(
             f"make_gus_mesh({n_shards}, pod={pod}): needs {need} device(s) "
-            f"but only {have} visible; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={need} "
-            "before jax initializes")
+            f"but only {have} {platform} device(s) are visible; {hint}")
     devices = jax.devices()[pod * n_shards:need]
     if two_level:
         # largest divisor <= sqrt becomes the outer "data" dim, so "model"
         # (the stage-1 gather) gets the bigger factor, as in production
         data = max(d for d in range(1, int(n_shards ** 0.5) + 1)
                    if n_shards % d == 0)
-        return _make_mesh((data, n_shards // data), ("data", "model"),
+        return _auto_mesh((data, n_shards // data), ("data", "model"),
                           devices=devices)
-    return _make_mesh((n_shards,), ("data",), devices=devices)
+    return _auto_mesh((n_shards,), ("data",), devices=devices)
 
 
 def make_pod_meshes(n_pods: int, n_shards: int, *, two_level: bool = False):

@@ -25,18 +25,21 @@ from repro.core.scorer import train_scorer
 from repro.data.stream import MutationStream, StreamConfig
 from repro.data.synthetic import (OGB_ARXIV_LIKE, OGB_PRODUCTS_LIKE,
                                   labeled_pairs, make_dataset)
+from repro.graph import GraphConfig
+from repro.launch.cache import configure_compile_cache
 from repro.serve.engine import EngineConfig, GusEngine
 
 DATASETS = {"arxiv": OGB_ARXIV_LIKE, "products": OGB_PRODUCTS_LIKE}
 
 
 def gus_config(n_points: int, *, scann_nn=10, idf_size=0, filter_percent=0.0,
-               backend="scann", shards=1) -> GusConfig:
+               backend="scann", shards=1,
+               graph: GraphConfig | None = None) -> GusConfig:
     """Serving config sized to the corpus, for any backend."""
     n_parts = max(16, n_points // 256)
     return GusConfig(
         scann_nn=scann_nn, idf_size=idf_size, filter_percent=filter_percent,
-        backend=backend,
+        backend=backend, graph=graph,
         scann=ScannConfig(d_proj=64, n_partitions=n_parts,
                           nprobe=8, reorder=max(128, scann_nn * 4)),
         sharded=ShardedConfig(
@@ -48,10 +51,11 @@ def gus_config(n_points: int, *, scann_nn=10, idf_size=0, filter_percent=0.0,
 
 def build_engine(dataset: str, n_points: int, *, scann_nn=10, idf_size=0,
                  filter_percent=0.0, backend="scann", shards=1,
-                 replicas=0, seed=0,
+                 replicas=0, seed=0, graph: GraphConfig | None = None,
                  engine_cfg: EngineConfig = EngineConfig()):
     """Bootstrap a full serving engine; ``replicas`` extra DynamicGUS
-    instances (same corpus) back the straggler-hedging path."""
+    instances (same corpus) back the straggler-hedging path; ``graph``
+    adds the maintained top-k graph."""
     data_cfg = dataclasses.replace(DATASETS[dataset], n_points=n_points)
     ids, feats, cluster = make_dataset(data_cfg)
     pf, lbl = labeled_pairs(feats, cluster, min(4 * n_points, 20000),
@@ -62,7 +66,7 @@ def build_engine(dataset: str, n_points: int, *, scann_nn=10, idf_size=0,
                         scalar_widths=(2.0,))
     cfg = gus_config(n_points, scann_nn=scann_nn, idf_size=idf_size,
                      filter_percent=filter_percent, backend=backend,
-                     shards=shards)
+                     shards=shards, graph=graph)
     stream = MutationStream(data_cfg, StreamConfig(seed=seed),
                             bootstrap_fraction=0.6)
     boot_ids, boot_feats = stream.bootstrap()
@@ -88,9 +92,9 @@ def main():
     ap.add_argument("--backend", choices=("scann", "brute", "sharded"),
                     default="scann")
     ap.add_argument("--shards", type=int, default=1,
-                    help="index shards for --backend sharded (needs "
-                         "XLA_FLAGS=--xla_force_host_platform_device_count"
-                         "=N set before launch)")
+                    help="index shards for --backend sharded (one "
+                         "device each; CPU runs need XLA_FLAGS=--xla_force_"
+                         "host_platform_device_count=N set before launch)")
     ap.add_argument("--replicas", type=int, default=0,
                     help="replica fleet size backing straggler hedging")
     ap.add_argument("--pipeline", action="store_true",
@@ -108,10 +112,12 @@ def main():
                          "default)")
     args = ap.parse_args()
 
+    configure_compile_cache()
     if args.shards > len(jax.devices()):
         raise SystemExit(
-            f"--shards {args.shards} needs {args.shards} devices; run with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={args.shards}")
+            f"--shards {args.shards} needs {args.shards} devices, "
+            f"{len(jax.devices())} visible; on CPU run with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={args.shards}")
     engine, stream, cluster = build_engine(
         args.dataset, args.points, scann_nn=args.scann_nn,
         idf_size=args.idf_size, filter_percent=args.filter_percent,

@@ -149,7 +149,6 @@ def make_compressed_dp_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
     an ``ef`` pytree holding the per-leaf quantization residual.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     loss_fn = make_loss_fn(cfg)
 
@@ -186,12 +185,12 @@ def make_compressed_dp_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
         p_specs = jax.tree.map(lambda _: P(), params)
         o_specs = jax.tree.map(lambda _: P(), opt_state)
         b_specs = jax.tree.map(lambda _: P(data_axis), batch)
-        fn = shard_map(per_shard, mesh=mesh,
-                       in_specs=(p_specs, o_specs, b_specs),
-                       out_specs=(p_specs, o_specs, jax.tree.map(
-                           lambda _: P(), jax.eval_shape(
-                               lambda: {"loss": jnp.float32(0)})["loss"])),
-                       check_rep=False)
+        fn = jax.shard_map(per_shard, mesh=mesh,
+                           in_specs=(p_specs, o_specs, b_specs),
+                           out_specs=(p_specs, o_specs, jax.tree.map(
+                               lambda _: P(), jax.eval_shape(
+                                   lambda: {"loss": jnp.float32(0)})["loss"])),
+                           check_vma=False)
         # out metrics spec built dynamically below instead
         return fn(params, opt_state, batch)
 
@@ -201,10 +200,10 @@ def make_compressed_dp_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
         o_specs = jax.tree.map(lambda _: P(), opt_state)
         b_specs = jax.tree.map(lambda _: P(data_axis), batch)
         m_specs = {"loss": P(), "moe_aux": P(), "grad_norm": P(), "lr": P()}
-        fn = shard_map(per_shard, mesh=mesh,
-                       in_specs=(p_specs, o_specs, b_specs),
-                       out_specs=(p_specs, o_specs, m_specs),
-                       check_rep=False)
+        fn = jax.shard_map(per_shard, mesh=mesh,
+                           in_specs=(p_specs, o_specs, b_specs),
+                           out_specs=(p_specs, o_specs, m_specs),
+                           check_vma=False)
         return fn(params, opt_state, batch)
 
     return train_step

@@ -35,7 +35,7 @@ def test_sharded_query_matches_local_oracle():
         from repro.ann.sharded import (GusCellConfig, index_shapes,
                                        make_query_step)
         from repro.core.types import PAD_INDEX
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
 
         mesh = make_test_mesh((2, 4), ("data", "model"))
         cell = GusCellConfig(n_rows=8*64, k_dims=4, d_proj=16, pq_m=4,
@@ -60,7 +60,7 @@ def test_sharded_query_matches_local_oracle():
         q_val = jnp.asarray(rng.random((8, cell.k_dims)), jnp.float32)
         q_sk = jnp.asarray(rng.normal(size=(8, cell.d_proj)), jnp.float32)
         import dataclasses as dc
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             step = make_query_step(mesh, cell)
             rows, dists = jax.jit(step)(q_idx, q_val, q_sk, state)
             hier = make_query_step(mesh, dc.replace(cell, merge="hier"))
@@ -100,7 +100,7 @@ def test_sharded_mutate_routes_and_tombstones():
         from repro.ann.sharded import (GusCellConfig, make_delete_step,
                                        make_mutate_step, PAD_ID)
         from repro.core.types import PAD_INDEX
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
 
         mesh = make_test_mesh((2, 4), ("data", "model"))
         cell = GusCellConfig(k_dims=4, d_proj=16, pq_m=4, n_partitions=16,
@@ -133,7 +133,7 @@ def test_sharded_mutate_routes_and_tombstones():
             rng.normal(size=(cell.mutate_batch, cell.d_proj)), jnp.float32)
         new_codes = jnp.asarray(
             rng.integers(0, 256, (cell.mutate_batch, cell.pq_m)), jnp.uint8)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             mutate = jax.jit(make_mutate_step(mesh, cell))
             state, (r_part, r_pos) = mutate(
                 jnp.asarray(ids), new_idx, new_val, new_sk, new_codes, state)
@@ -181,7 +181,6 @@ def test_compressed_dp_step_trains():
                                             make_compressed_dp_train_step,
                                             init_ef_state, make_train_step)
         cfg = reduced_config("qwen3-8b")
-        from repro.launch.mesh import mesh_context
         mesh = make_test_mesh((8,), ("data",))
         opt = AdamWConfig(lr=1e-3)
         params, opt_state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
@@ -189,7 +188,7 @@ def test_compressed_dp_step_trains():
         step = make_compressed_dp_train_step(cfg, opt, mesh)
         rng = np.random.default_rng(0)
         losses = []
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             jit_step = jax.jit(step)
             for i in range(8):
                 batch = {"tokens": jnp.asarray(rng.integers(0, 64, (16, 16))),

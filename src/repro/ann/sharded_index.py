@@ -887,7 +887,11 @@ class ShardedGusIndex:
         k_eff = min(k, r)
         out_ids = np.full((b, k), -1, np.int64)
         out_d = np.full((b, k), np.inf, np.float32)
-        sk = np.asarray(self._sketch(emb))
+        tracer = self.obs.tracer
+        with tracer.span("sketch"):
+            sk = self._sketch(emb)
+            with tracer.span("device_wait"):
+                sk = np.asarray(sk)
         step_b = pow2_pad(b, cfg.query_batch)
         for lo in range(0, b, step_b):
             sel = slice(lo, min(lo + step_b, b))
@@ -903,8 +907,9 @@ class ShardedGusIndex:
             with jax.set_mesh(self.mesh):
                 rows, dists = step(jnp.asarray(q_idx), jnp.asarray(q_val),
                                    jnp.asarray(q_sk), self.state)
-            rows = np.asarray(rows)[:n_c]
-            dists = np.asarray(dists)[:n_c]
+            with tracer.span("device_wait"):
+                rows = np.asarray(rows)[:n_c]
+                dists = np.asarray(dists)[:n_c]
             hit = np.isfinite(dists)
             if hit.any():
                 # per-partition read-traffic counters: every returned

@@ -20,7 +20,10 @@ protocol, so the RPC surfaces below are backend-agnostic; ``serve.engine``
 adds batching, hedging against replicas, and fault recovery on top.
 
 Latency accounting mirrors the paper's Fig. 9/10: per-RPC wall-clock
-timers for mutation and neighborhood paths.
+timers for mutation and neighborhood paths. Inside a traced request the
+neighborhood path opens ``embed`` and ``score`` spans (``score`` holding
+``gather`` and the ``device_wait`` for the scorer's weights) on the
+telemetry plane an engine binds (``bind_telemetry``).
 """
 from __future__ import annotations
 
@@ -42,6 +45,7 @@ from repro.core.types import (FeatureSpec, MutationBatch, NeighborResult,
 from repro.graph.store import DynamicGraphStore, GraphConfig
 from repro.multimodal import (MultiModalConfig, MultiModalStore,
                               two_stage_neighbors)
+from repro.obs import Telemetry
 from repro.utils.timing import Timer
 
 
@@ -181,6 +185,14 @@ class DynamicGUS:
         self.mutation_timer = Timer("mutation")
         self.query_timer = Timer("neighbors")
         self.graph_timer = Timer("graph")
+        # standalone engines get a private telemetry plane that nothing
+        # activates; a GusEngine binds its primary into the shared one
+        self.obs = Telemetry()
+
+    def bind_telemetry(self, telemetry: Telemetry) -> None:
+        """Join a shared telemetry plane: the query path's spans attach to
+        the traces its tracer activates."""
+        self.obs = telemetry
 
     # ----------------------------------------------------- offline (§4.3)
 
@@ -395,18 +407,24 @@ class DynamicGUS:
         if self.multimodal is not None:
             return two_stage_neighbors(self, features, k, exclude_ids,
                                        emb=emb, buckets=buckets)
+        tracer = self.obs.tracer
         if emb is None:
-            emb = self.embedder(features)
+            with tracer.span("embed"):
+                emb = self.embedder(features)
         ids, dists = self.index.search(emb, k + (exclude_ids is not None))
         if exclude_ids is not None:
             ids, dists = _drop_self(ids, dists, np.asarray(exclude_ids), k)
-        cand_feats = self.store.gather(ids)
-        flat_q = {kk: np.repeat(np.asarray(v), ids.shape[1], axis=0)
-                  for kk, v in features.items()}
-        flat_c = {kk: v.reshape((-1,) + v.shape[2:])
-                  for kk, v in cand_feats.items()}
-        weights = np.asarray(scorer_apply(
-            self.scorer_params, pair_features(flat_q, flat_c, self.spec)))
+        with tracer.span("score"):
+            with tracer.span("gather"):
+                cand_feats = self.store.gather(ids)
+            flat_q = {kk: np.repeat(np.asarray(v), ids.shape[1], axis=0)
+                      for kk, v in features.items()}
+            flat_c = {kk: v.reshape((-1,) + v.shape[2:])
+                      for kk, v in cand_feats.items()}
+            weights = scorer_apply(
+                self.scorer_params, pair_features(flat_q, flat_c, self.spec))
+            with tracer.span("device_wait"):
+                weights = np.asarray(weights)
         weights = weights.reshape(ids.shape)
         weights = np.where(ids >= 0, weights, -np.inf)
         return NeighborResult(ids=ids, weights=weights.astype(np.float32),

@@ -28,8 +28,8 @@ import time
 from repro.obs.events import Event, EventLog
 from repro.obs.registry import (DEFAULT_MS_BUCKETS, Counter, Gauge,
                                 Histogram, MetricsRegistry)
-from repro.obs.trace import (NULL_TRACE, NullTrace, Span, Trace, Tracer,
-                             latency_breakdown)
+from repro.obs.trace import (APPLIED_ROOT, NULL_TRACE, NullTrace, Span,
+                             Trace, Tracer, latency_breakdown)
 
 # default per-request trace sampling: every 16th request group carries a
 # span tree (0 = off, 1 = always-on; the overhead gate in

@@ -26,10 +26,12 @@ needs (paper §3.1 runs at "hundreds of thousands of RPCs per second"):
   mutation-log suffix they missed (or re-bootstraps from the snapshot
   when the log no longer reaches back far enough) before they serve
   again;
-* **freshness accounting** — per-mutation timestamps measure visibility
-  lag (the paper's "data freshness within seconds at p99"); ``serving``
-  records per-request effective latency (hedges and injected straggler
-  time included) for the p95/p99-under-load metrics;
+* **freshness accounting** — each batch's submit time is stamped and
+  ``engine_freshness_ms`` observes submit-to-applied on the primary (the
+  paper's "data freshness within seconds at p99"): when ``mutate``
+  returns, or when the pipeline's hand-off that applies the batch ends.
+  ``serving`` records per-request effective latency (hedges and injected
+  straggler time included) for the p95/p99-under-load metrics;
 * **mutation log + snapshot restart** — every submitted batch is
   appended to a host-side log; ``recover()`` replays the suffix after a
   crash/restart. Snapshots are the *composed* ``SnapshotStateful`` dict
@@ -57,7 +59,7 @@ import numpy as np
 
 from repro.core.gus import DynamicGUS
 from repro.core.types import MutationBatch, NeighborResult
-from repro.obs import Telemetry
+from repro.obs import APPLIED_ROOT, Telemetry
 from repro.serve.faults import FaultInjector
 from repro.serve.pipeline import MutationPipeline, PipelineConfig
 from repro.serve.replica import Replica, ReplicaSet
@@ -120,7 +122,8 @@ class GusEngine:
         self.serving = reg.histogram(
             "engine_serving_ms", "per-request effective serving latency")
         self.freshness = reg.histogram(
-            "engine_freshness_ms", "mutation submit-to-visible latency")
+            "engine_freshness_ms",
+            "mutation submit-to-applied latency on the primary")
         self.service = reg.histogram(
             "engine_service_ms", "first eligible member's answer time")
         self.hedge_wait = reg.histogram(
@@ -133,8 +136,13 @@ class GusEngine:
         self.pipelines: list[MutationPipeline] = []
         if cfg.pipeline:
             pcfg = PipelineConfig(repair_per_tick=cfg.repair_per_tick)
-            self.pipelines = [MutationPipeline(g, pcfg, telemetry=self.obs)
-                              for g in (gus, *replicas)]
+            # the primary's pipeline reports when each batch is applied
+            self.pipelines = [
+                MutationPipeline(g, pcfg, telemetry=self.obs,
+                                 on_applied=self._applied
+                                 if g is gus else None)
+                for g in (gus, *replicas)]
+        gus.bind_telemetry(self.obs)     # query-path spans join the plane
         bind = getattr(gus.index, "bind_telemetry", None)
         if callable(bind):
             bind(self.obs)           # sharded backend joins the registry
@@ -224,18 +232,31 @@ class GusEngine:
             if not member.alive or member.partitioned:
                 continue                      # falls behind; catch_up later
             if pipe is not None:
-                pipe.submit(batch)
+                pipe.submit(batch, t_submit=t0)
             else:
                 member.gus.mutate(batch)
+                if member is self.primary:
+                    self._applied([t0])
             member.applied_seq = self.seq
         self.mutation_log.append(batch)
         self.log_since_snapshot += 1
-        # visibility lag: synchronous mutations are visible when mutate()
-        # returns; pipelined ones when the next hand-off completes (the
-        # engine flushes before any read, so this is the submit latency)
-        self.freshness.record(time.perf_counter() - t0)
         if self.log_since_snapshot >= self.cfg.snapshot_every:
             self.snapshot()
+
+    def _applied(self, submitted: list) -> None:
+        """Batches submitted at ``submitted`` (``time.perf_counter``) are
+        applied on the primary now: synchronous ones when ``mutate``
+        returns, pipelined ones when the hand-off that applied them ends.
+        Observes each one's submit-to-applied latency and records it as an
+        ``apply_lag`` span in a trace of its own, so that no span open
+        now (``handoff``, ``flush``) is widened back to the submit."""
+        now = time.perf_counter()
+        tracer = self.obs.tracer
+        for t_submit in submitted:
+            lag_s = now - t_submit
+            self.freshness.record(lag_s)
+            t1 = tracer.clock()
+            tracer.add_apart(APPLIED_ROOT, "apply_lag", t1 - lag_s, t1)
 
     def flush(self) -> None:
         """Barrier for the async write path: after this, every submitted
@@ -298,7 +319,12 @@ class GusEngine:
 
         Tracing: when a caller (the front-end) has already activated a
         trace, the engine's spans attach to it; when called directly the
-        engine owns a trace of its own for the sampled request."""
+        engine owns a trace of its own for the sampled request. The tree
+        is ``engine_query`` -> ``flush`` / ``catch_up`` / ``route``, and
+        under ``route`` each answer it waits for (``answer_primary``,
+        ``answer_hedge``, ``answer_failover``), opened live around the
+        member's ``neighbors`` so the query path's own spans (``embed``,
+        ``shard_search``, ``score``) nest inside it."""
         self._c_queries.inc()
         tracer = self.obs.tracer
         owned = None
@@ -332,13 +358,14 @@ class GusEngine:
                       span: str = "answer_primary"):
         """One member's answer + its effective latency (measured plus any
         injected straggler ms; the injected part lands in the span's
-        ``extra_ms`` meta, never in its wall-clock bounds)."""
-        t0 = time.perf_counter()
-        res = member.gus.neighbors(feats, k)
-        t1 = time.perf_counter()
+        ``extra_ms`` meta, never in its wall-clock bounds). The span is
+        live around the answer, so the member's own spans nest in it."""
         extra_ms = self.faults.extra_ms(member.key)
-        self.obs.tracer.add_span(span, t0, t1, member=member.name,
-                                 extra_ms=extra_ms)
+        with self.obs.tracer.span(span, member=member.name,
+                                  extra_ms=extra_ms):
+            t0 = time.perf_counter()
+            res = member.gus.neighbors(feats, k)
+            t1 = time.perf_counter()
         return res, (t1 - t0) * 1e3 + extra_ms
 
     def _route(self, feats, k):

@@ -123,7 +123,6 @@ from repro.core.gus import DynamicGUS, StagedMutation
 from repro.core.types import MutationBatch, MUTATION_DELETE
 from repro.obs import Telemetry
 from repro.serve.maintenance import MaintenanceWorker
-from repro.utils.timing import Timer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,9 +156,13 @@ class MutationPipeline:
 
     def __init__(self, gus: DynamicGUS,
                  cfg: PipelineConfig = PipelineConfig(),
-                 telemetry: Telemetry | None = None):
+                 telemetry: Telemetry | None = None,
+                 on_applied=None):
         self.gus = gus
         self.cfg = cfg
+        # called with the submit times of the batches a hand-off applied,
+        # when it ends (the engine's submit-to-applied accounting)
+        self.on_applied = on_applied
         # plane-wide instruments (the engine shares one Telemetry across
         # its per-member pipelines, so these aggregate the whole write
         # path; the per-pipeline describe() view keeps its own counts)
@@ -187,8 +190,10 @@ class MutationPipeline:
             gus, telemetry=self.obs, repair_per_tick=cfg.repair_per_tick)
         self._queue: list[MutationBatch] = []     # accumulating window
         self._queue_ids: set = set()              # upserted ids staged
+        self._queue_submitted: list = []          # submit time per batch
         self._inflight: StagedMutation | None = None
         self._inflight_ids: set = set()           # upserted ids in flight
+        self._inflight_submitted: list = []       # one per fused batch
         # backends whose update path re-routes free-list slots (scann)
         # cannot fuse updates of live ids bit-exactly — fall back to a
         # window boundary before them
@@ -216,13 +221,10 @@ class MutationPipeline:
                            and gus.multimodal.cfg.reload_every > 0)
         self._queued_rows = 0         # upsert rows staged in the window
         self._inflight_rows = 0       # upsert rows in the in-flight window
-        self._inflight_batches = 0    # batches fused into the in-flight window
         self.submitted = 0            # points acknowledged
         self.windows = 0              # fused windows encoded
         self.ticks = 0                # completed hand-offs
         self.repaired = 0             # repair re-queries drained
-        self.encode_timer = Timer("pipeline_encode")
-        self.handoff_timer = Timer("pipeline_handoff")
 
     @property
     def in_flight(self) -> bool:
@@ -251,10 +253,12 @@ class MutationPipeline:
             return 1
         return max(1, self.cfg.window)
 
-    def submit(self, batch: MutationBatch) -> int:
+    def submit(self, batch: MutationBatch,
+               t_submit: float | None = None) -> int:
         """Stage the batch. Returns the number of points acknowledged
         (they become query-visible at the next hand-off — ``flush()``
-        forces it)."""
+        forces it). ``t_submit`` (``time.perf_counter``) is handed to
+        ``on_applied`` when that hand-off ends."""
         kinds = np.asarray(batch.kinds)
         ids = np.asarray(batch.ids)
         has_del = bool((kinds == MUTATION_DELETE).any())
@@ -281,6 +285,7 @@ class MutationPipeline:
                 else "window_full" if len(self._queue) >= self.window_size()
                 else "duplicate_ids")
         self._queue.append(batch)
+        self._queue_submitted.append(t_submit)
         self._queue_ids |= up_ids
         self._queued_rows += len(up_ids)
         self.submitted += int(ids.size)
@@ -318,18 +323,18 @@ class MutationPipeline:
         fused = fuse_batches(self._queue)
         queue_ids = self._queue_ids
         queue_rows = self._queued_rows
-        queue_batches = len(self._queue)
+        queue_submitted = self._queue_submitted
         self._queue = []
         self._queue_ids = set()
+        self._queue_submitted = []
         self._queued_rows = 0
         with self.obs.tracer.span("encode", batches=len(fused.ids)):
             t0 = time.perf_counter()
             staged = self.gus.encode_mutation(fused)
             t_encode = time.perf_counter() - t0
-        self.encode_timer.record(t_encode)
         self._h_encode.record(t_encode)
         # mutation latency in pipelined mode = the stage-A dispatch; the
-        # window's apply/barrier overlaps later submits (handoff timer)
+        # window's apply/barrier overlaps later submits (pipeline_handoff_ms)
         self.gus.mutation_timer.record(t_encode)
         self.windows += 1
         self._c_windows.inc()
@@ -337,19 +342,19 @@ class MutationPipeline:
         self._inflight = staged
         self._inflight_ids = queue_ids
         self._inflight_rows = queue_rows
-        self._inflight_batches = queue_batches
+        self._inflight_submitted = queue_submitted
 
     def _handoff(self) -> None:
         staged = self._inflight
         if staged is None:
             return
-        n_batches = self._inflight_batches
+        submitted = self._inflight_submitted
+        n_batches = len(submitted)
         self._inflight = None
         self._inflight_ids = set()
         self._inflight_rows = 0
-        self._inflight_batches = 0
-        with self.obs.tracer.span("handoff"), self.handoff_timer, \
-                self._h_handoff:
+        self._inflight_submitted = []
+        with self.obs.tracer.span("handoff"), self._h_handoff:
             # stage B: the encode results dispatched at window close have
             # had the whole in-flight window to compute — materializing
             # them (inside apply) no longer waits on the device
@@ -377,12 +382,14 @@ class MutationPipeline:
                         self._c_repaired.inc(repaired)
         self.ticks += 1
         self._c_ticks.inc()
+        if self.on_applied is not None:
+            self.on_applied(submitted)
         if self.bound > 0:
             self.worker.settle()
 
     def describe(self) -> dict:
-        """Structured pipeline state (counters, timer summaries, and the
-        maintenance plane's ledger)."""
+        """Structured pipeline state (counters, the plane's encode and
+        hand-off histograms, and the maintenance plane's ledger)."""
         out = {
             "submitted": self.submitted,
             "windows": self.windows,
@@ -390,8 +397,8 @@ class MutationPipeline:
             "staged_batches": len(self._queue),
             "in_flight": self.in_flight,
             "repaired": self.repaired,
-            "encode": self.encode_timer.summary(),
-            "handoff": self.handoff_timer.summary(),
+            "encode": self._h_encode.summary(),
+            "handoff": self._h_handoff.summary(),
             "maintenance": self.worker.describe(),
         }
         if self.gus.graph is not None:

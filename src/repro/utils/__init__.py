@@ -1,4 +1,3 @@
-from repro.utils.tree import tree_size_bytes, tree_param_count, tree_cast
 from repro.utils.timing import Timer, percentiles
 
 

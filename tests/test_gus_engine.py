@@ -2,6 +2,7 @@
 straggler hedging against real replicas — plus edge cases of the
 neighborhood RPC helpers (``_drop_self`` / ``neighbors_of_ids``)."""
 import dataclasses
+import time
 
 import jax
 import numpy as np
@@ -259,6 +260,130 @@ def test_unsampled_queries_leave_no_traces(world):
     assert len(engine.obs.tracer.finished) == 0
     assert engine.obs.tracer.started == 3             # decisions still taken
     assert engine.queries == 3                        # counters always on
+
+
+SMALL_SHARDED = ShardedConfig(n_shards=1, d_proj=32, n_partitions=8,
+                              nprobe_local=0, reorder=1024, pq_m=4,
+                              kmeans_iters=4, pq_iters=2)
+
+
+@pytest.fixture(scope="module")
+def sharded_gus(world):
+    """A booted one-shard sharded primary that the query-path span tests
+    share (they only read it)."""
+    ids, feats, cluster, scorer = world
+    gus = _gus(scorer, backend="sharded", sharded=SMALL_SHARDED)
+    _boot(gus, ids, feats)
+    return gus
+
+
+def _descends(trace, span, ancestor) -> bool:
+    while span.parent >= 0:
+        span = trace.spans[span.parent]
+        if span is ancestor:
+            return True
+    return False
+
+
+def _child_names(trace, parent) -> set:
+    idx = trace.spans.index(parent)
+    return {s.name for s in trace.spans if s.parent == idx}
+
+
+def test_query_path_spans_nest_under_the_answer(world, sharded_gus):
+    """On the sharded backend, the answer span is live around the
+    member's answer: embed, shard_search (sketch, device waits) and score
+    (gather, the weights' device wait) are its descendants."""
+    ids, feats, cluster, scorer = world
+    engine = GusEngine(sharded_gus, EngineConfig(hedge_ms=1e9))
+    engine.obs.tracer.sample_every = 1
+    engine.query({k: v[:2] for k, v in feats.items()}, k=5)
+    tr = engine.obs.tracer.finished[-1]
+    assert tr.problems() == []
+    answer = tr.find("answer_primary")[0]
+    assert _child_names(tr, answer) == {"embed", "shard_search", "score"}
+    search = tr.find("shard_search")[0]
+    assert _child_names(tr, search) == {"sketch", "device_wait"}
+    assert _child_names(tr, tr.find("sketch")[0]) == {"device_wait"}
+    score = tr.find("score")[0]
+    assert _child_names(tr, score) == {"gather", "device_wait"}
+    for name in ("embed", "shard_search", "sketch", "device_wait", "score",
+                 "gather"):
+        for sp in tr.find(name):
+            assert _descends(tr, sp, answer), name
+    assert tr.spans[answer.parent].name == "route"
+
+
+def test_hedge_spans_match_the_hedge_counter(world, sharded_gus):
+    """With a zero deadline and no replica every answer is reissued on the
+    primary: one answer_hedge span per counted hedge."""
+    ids, feats, cluster, scorer = world
+    engine = GusEngine(sharded_gus, EngineConfig(hedge_ms=0.0))
+    engine.obs.tracer.sample_every = 1
+    hedges = engine.obs.registry.get("engine_hedges_total")
+    before = hedges.value
+    for lo in range(3):
+        engine.query({k: v[lo:lo + 1] for k, v in feats.items()}, k=5)
+    spans = sum(len(tr.find("answer_hedge"))
+                for tr in engine.obs.tracer.finished)
+    assert spans == hedges.value - before == 3
+    assert all(tr.problems() == [] for tr in engine.obs.tracer.finished)
+
+
+def test_pipelined_freshness_counts_submit_to_applied(world):
+    """A pipelined batch applied 50 ms after its submit reads at least
+    50 ms of freshness, and its apply_lag span, in a trace of its own,
+    leaves the hand-off and flush spans that applied it as measured."""
+    ids, feats, cluster, scorer = world
+    gus = _gus(scorer)
+    _boot(gus, ids, feats)
+    engine = GusEngine(gus, EngineConfig(pipeline=True))
+    tracer = engine.obs.tracer
+    tracer.sample_every = 1
+    delay_s = 0.05
+    engine.submit_mutations(MutationBatch(
+        kinds=np.full(16, MUTATION_INSERT, np.int32), ids=ids[200:216],
+        features={k: v[200:216] for k, v in feats.items()}))
+    assert engine.freshness.count == 0            # staged, not applied
+    t_wait = tracer.clock()
+    time.sleep(delay_s)
+    engine.query({k: v[200:201] for k, v in feats.items()}, k=3)
+    assert engine.freshness.count == 1
+    assert engine.freshness.samples_ms[-1] >= delay_s * 1e3
+    lag_traces = [tr for tr in tracer.finished if tr.find("apply_lag")]
+    assert len(lag_traces) == 1
+    lag = lag_traces[0].find("apply_lag")[0]
+    assert lag is not lag_traces[0].root and lag.duration_ms >= delay_s * 1e3
+    query = [tr for tr in tracer.finished if tr.find("handoff")][-1]
+    assert query is not lag_traces[0] and query.problems() == []
+    for name in ("handoff", "flush", "engine_query"):
+        assert query.find(name)[0].t0 >= t_wait + delay_s, name
+    assert lag.t0 < t_wait
+
+
+def test_pipelined_loadgen_breakdown_counts_only_queries(world):
+    """A traced, pipelined load run with mutations records apply_lag
+    traces beside the request traces; the latency breakdown takes one
+    service sample per query request and none per apply_lag."""
+    from benchmarks.loadgen import LoadgenConfig, run_loadgen
+    from repro.serve import Frontend, FrontendConfig
+
+    ids, feats, cluster, scorer = world
+    gus = _gus(scorer)
+    _boot(gus, ids, feats)
+    engine = GusEngine(gus, EngineConfig(pipeline=True))
+    engine.obs.tracer.sample_every = 1
+    fe = Frontend(engine, FrontendConfig(query_dispatch=2))
+    stream = MutationStream(DATA, StreamConfig(batch_size=8, seed=5),
+                            bootstrap_fraction=0.5)
+    rep = run_loadgen(fe, stream, LoadgenConfig(
+        mode="closed", requests=24, users=4, mutate_every=3, k=5))
+    assert rep.lost == 0 and rep.errors == 0
+    n_queries = rep.completed - len(engine.mutation_log)
+    assert any(tr.find("apply_lag") for tr in engine.obs.tracer.finished)
+    assert rep.breakdown["queue_wait"]["n"] == n_queries
+    assert rep.breakdown["service"]["n"] == n_queries
+    assert rep.breakdown["hedge_wait"]["n"] == n_queries
 
 
 # ---------------------------------------- _drop_self / neighbors_of_ids

@@ -1,8 +1,13 @@
 """Unit contract of the ``repro.obs`` telemetry plane: registry
 get-or-create and exporters, span-tree well-formedness (including the
-backdated ``add_span`` anchoring rule), sampling arithmetic, the event
-ring, and the trace -> latency-breakdown reconstruction."""
+backdated ``add_span`` anchoring rule), the profiler annotation of live
+spans, sampling arithmetic, the event ring, and the trace ->
+latency-breakdown reconstruction."""
+import contextlib
 import json
+import subprocess
+import sys
+from pathlib import Path
 
 import pytest
 
@@ -150,6 +155,86 @@ def test_tracer_activate_is_ambient_and_nestable():
         tracer.add_span("late", tr.root.t0, tr.root.t0)
     assert tracer.active is None                      # restored on exit
     assert [s.name for s in tr.spans] == ["request", "inner", "late"]
+
+
+class FakeAnnotations:
+    """An annotation factory that logs each enter and exit by name."""
+
+    def __init__(self):
+        self.log = []
+
+    @contextlib.contextmanager
+    def __call__(self, name):
+        self.log.append(("enter", name))
+        try:
+            yield
+        finally:
+            self.log.append(("exit", name))
+
+
+def test_live_spans_enter_one_annotation_each_nested_as_the_tree():
+    ann = FakeAnnotations()
+    tracer = Tracer(sample_every=1, annotation=ann)
+    tr = tracer.trace("request")
+    with tracer.activate(tr):
+        with tracer.span("answer_primary"):
+            with tracer.span("embed"):
+                pass
+            with tracer.span("score"):
+                with tracer.span("gather"):
+                    pass
+        tracer.add_span("queue_wait", tr.root.t0, tr.root.t0)  # backdated
+    tracer.collect(tr)
+    assert ann.log == [
+        ("enter", "answer_primary"), ("enter", "embed"), ("exit", "embed"),
+        ("enter", "score"), ("enter", "gather"), ("exit", "gather"),
+        ("exit", "score"), ("exit", "answer_primary")]
+    assert tr.problems() == []
+
+
+@pytest.mark.parametrize("sample_every", [1, 0],
+                         ids=["null_trace", "sample_every_0"])
+def test_unsampled_spans_enter_no_annotation(sample_every):
+    """The shared NULL_TRACE, and every trace of a disabled tracer."""
+    ann = FakeAnnotations()
+    tracer = Tracer(sample_every=sample_every, annotation=ann)
+    tr = tracer.trace("request") if sample_every == 0 else NULL_TRACE
+    assert tr is NULL_TRACE
+    with tracer.activate(tr):
+        with tracer.span("answer_primary"):
+            with tracer.span("embed"):
+                pass
+    with tr.span("direct"):
+        pass
+    assert ann.log == []
+
+
+def test_add_apart_widens_no_open_span():
+    t = [10.0]
+    tracer = Tracer(sample_every=1, clock=lambda: t[0],
+                    annotation=FakeAnnotations())
+    tr = tracer.trace("request")
+    with tracer.activate(tr):
+        with tracer.span("handoff"):
+            t[0] = 11.0
+            tracer.add_apart("applied", "apply_lag", 2.0, 11.0)
+    tracer.collect(tr)
+    assert tr.find("handoff")[0].t0 == 10.0 and tr.root.t0 == 10.0
+    apart = tracer.finished[0]
+    assert apart.root.name == "applied" and apart.problems() == []
+    (lag,) = apart.find("apply_lag")
+    assert lag.parent == 0 and (lag.t0, lag.t1) == (2.0, 11.0)
+    idle = Tracer(sample_every=1)
+    assert idle.add_apart("applied", "apply_lag", 0.0, 1.0) is None
+
+
+def test_obs_imports_without_jax():
+    """The profiler annotation imports JAX only on the first live span."""
+    import repro
+    src = str(Path(repro.__path__[0]).parent)
+    code = ("import sys; sys.path.insert(0, sys.argv[1]); import repro.obs; "
+            "sys.exit(1 if 'jax' in sys.modules else 0)")
+    assert subprocess.run([sys.executable, "-c", code, src]).returncode == 0
 
 
 # ---------------------------------------------------------------- events

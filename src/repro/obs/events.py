@@ -8,9 +8,10 @@ catalog, docs/OBSERVABILITY.md):
 
   engine    — ``replica_down`` / ``replica_up`` / ``replica_partitioned``
               / ``replica_healed`` (health transitions observed at the
-              fault-injector sync), ``failover`` / ``hedge`` (routing
-              decisions), ``catch_up`` (freshness rejoin: member, batches
-              replayed, whether it re-bootstrapped from the snapshot),
+              fault-injector sync), ``failover`` / ``hedge`` /
+              ``hedge_skipped`` (routing decisions), ``catch_up``
+              (freshness rejoin: member, batches replayed, whether it
+              re-bootstrapped from the snapshot),
               ``snapshot``, ``unavailable``;
   frontend  — ``admission_shed`` (class + reason: the explicit rejection
               the admission contract promises);

@@ -14,10 +14,12 @@ needs (paper §3.1 runs at "hundreds of thousands of RPCs per second"):
 * **straggler hedging + fail-over** — if the primary's reply lags past
   the hedge deadline, the query reissues against the next *eligible*
   replica (round-robin; dead, partitioned, and stale members are
-  skipped). A dead primary fails over entirely; when nobody can serve,
-  the engine raises ``ServingUnavailableError`` — an explicit error, so
-  callers (the request front-end) answer the request rather than lose
-  it;
+  skipped). With no other eligible member the primary's answer, already
+  in hand, is served: a reissue on the primary would recompute it on the
+  same state (``engine_hedges_skipped_total`` counts these). A dead
+  primary fails over entirely; when nobody can serve, the engine raises
+  ``ServingUnavailableError`` — an explicit error, so callers (the
+  request front-end) answer the request rather than lose it;
 * **fault injection** — every health/latency decision consults an
   optional ``serve.faults.FaultInjector``: scripted kill / slow /
   partition faults steer routing deterministically (synthetic straggler
@@ -103,7 +105,12 @@ class GusEngine:
         self._c_queries = reg.counter(
             "engine_queries_total", "queries answered by the engine")
         self._c_hedges = reg.counter(
-            "engine_hedges_total", "queries reissued past the hedge deadline")
+            "engine_hedges_total",
+            "queries reissued on a replica past the hedge deadline")
+        self._c_hedges_skipped = reg.counter(
+            "engine_hedges_skipped_total",
+            "deadline missed, no other eligible member: the primary's "
+            "answer stands")
         self._c_failovers = reg.counter(
             "engine_failovers_total", "queries failed over off the primary")
         self._c_unavailable = reg.counter(
@@ -312,7 +319,8 @@ class GusEngine:
     def query(self, features: dict, k: int | None = None) -> NeighborResult:
         """Pad the query batch to a power of two, answer, unpad. Routing:
         primary if eligible, hedged against the next eligible replica past
-        the deadline; fail-over when the primary cannot serve; explicit
+        the deadline (the primary's answer stands when there is none);
+        fail-over when the primary cannot serve; explicit
         ``ServingUnavailableError`` when nobody can. Injected straggler
         latency is added to measured time (never slept) so hedging and
         the recorded serving latency respond to faults deterministically.
@@ -322,9 +330,10 @@ class GusEngine:
         engine owns a trace of its own for the sampled request. The tree
         is ``engine_query`` -> ``flush`` / ``catch_up`` / ``route``, and
         under ``route`` each answer it waits for (``answer_primary``,
-        ``answer_hedge``, ``answer_failover``), opened live around the
-        member's ``neighbors`` so the query path's own spans (``embed``,
-        ``shard_search``, ``score``) nest inside it."""
+        ``answer_hedge`` when a replica reissued it, ``answer_failover``),
+        opened live around the member's ``neighbors`` so the query path's
+        own spans (``embed``, ``shard_search``, ``score``) nest inside
+        it."""
         self._c_queries.inc()
         tracer = self.obs.tracer
         owned = None
@@ -376,22 +385,22 @@ class GusEngine:
             if elapsed_ms <= self.cfg.hedge_ms:
                 self.primary.served += 1
                 return res, elapsed_ms
+            replica = self.replica_set.pick(self.seq)
+            if replica is None:
+                # no other eligible member: a reissue could only run on the
+                # primary again, on the same state, for the same answer
+                self._c_hedges_skipped.inc()
+                self.obs.events.emit("hedge_skipped", primary_ms=elapsed_ms,
+                                     seq=self.seq)
+                self.primary.served += 1
+                return res, elapsed_ms
             self._c_hedges.inc()
             self.obs.events.emit("hedge", primary_ms=elapsed_ms,
                                  seq=self.seq)
-            replica = self.replica_set.pick(self.seq)
-            if replica is not None:
-                res, r_ms = self._timed_answer(
-                    replica, feats, k, "answer_hedge")
-                self.hedge_wait.observe(r_ms)
-                replica.hedges += 1
-                replica.served += 1
-                return res, elapsed_ms + r_ms
-            # no eligible replica fleet: reissue against the primary
-            res, r_ms = self._timed_answer(
-                self.primary, feats, k, "answer_hedge")
+            res, r_ms = self._timed_answer(replica, feats, k, "answer_hedge")
             self.hedge_wait.observe(r_ms)
-            self.primary.served += 1
+            replica.hedges += 1
+            replica.served += 1
             return res, elapsed_ms + r_ms
         # primary down/stale: fail over to the replica group
         replica = self.replica_set.pick(self.seq)

@@ -115,7 +115,7 @@ def test_chaos_closed_loop_single_device(world):
     mark = events.seq
     phase("partitioned")
     assert r1.hedges == hedges_part                # stale: never eligible
-    assert engine.primary.served > 0               # primary reissues
+    assert engine.primary.served > 0               # its answer stands
     parts = events.events("replica_partitioned", since=mark)
     assert [e["member"] for e in parts] == ["replica:1"]
 

@@ -18,6 +18,7 @@ from repro.data.synthetic import OGB_ARXIV_LIKE, labeled_pairs, make_dataset
 from repro.serve.engine import (EngineConfig, GusEngine,
                                 ServingUnavailableError)
 from repro.serve.faults import FaultInjector
+from repro.utils import pow2_pad
 
 DATA = dataclasses.replace(OGB_ARXIV_LIKE, n_points=400, n_clusters=8)
 BUCKETS = BucketConfig(dense_tables=8, dense_bits=10, scalar_widths=(2.0,))
@@ -157,14 +158,75 @@ def test_hedge_replicas_stay_mutation_consistent(world):
     assert not set(res.ids[res.ids >= 0].tolist()) & set(dels.tolist())
 
 
+def _skipped(engine) -> int:
+    return engine.obs.registry.get("engine_hedges_skipped_total").value
+
+
 def test_hedge_without_replicas_reissues_primary(world):
+    """A missed deadline with no replica reissues nothing: the primary's
+    answer stands, counted as a skipped hedge, not as a hedge."""
     ids, feats, cluster, scorer = world
     gus = _gus(scorer)
     _boot(gus, ids, feats)
     engine = GusEngine(gus, EngineConfig(hedge_ms=-1.0))
     res = engine.query({k: v[:1] for k, v in feats.items()}, k=5)
-    assert engine.hedged == 1 and engine.replica_hedges == []
+    assert engine.hedged == 0 and engine.replica_hedges == []
+    assert _skipped(engine) == 1
+    assert engine.primary.served == 1
+    assert engine.service.count == engine.serving.count == 1
+    assert engine.hedge_wait.count == 0
+    events = engine.obs.events
+    assert events.events("hedge") == []
+    ev = events.last("hedge_skipped")
+    assert ev["seq"] == engine.seq and ev["primary_ms"] >= 0.0
     assert res.ids.shape == (1, 5)
+
+
+def test_missed_deadline_without_replica_answers_once(world):
+    """Under a missed deadline with no replica, route holds one
+    answer_primary and no answer_hedge, and the served answer is the
+    primary's own on the same padded features, bit for bit."""
+    ids, feats, cluster, scorer = world
+    gus = _gus(scorer)
+    _boot(gus, ids, feats)
+    engine = GusEngine(gus, EngineConfig(hedge_ms=-1.0))
+    engine.obs.tracer.sample_every = 1
+    n = 3
+    q = {k: v[40:40 + n] for k, v in feats.items()}
+    res = engine.query(q, k=5)
+    tr = engine.obs.tracer.finished[-1]
+    assert tr.problems() == []
+    route = tr.spans.index(tr.find("route")[0])
+    answers = [sp.name for sp in tr.spans if sp.parent == route]
+    assert answers == ["answer_primary"]
+    assert tr.find("answer_hedge") == []
+    padded = pow2_pad(n, engine.cfg.query_batch)
+    feats_p = {k: np.concatenate([v, np.repeat(v[-1:], padded - n, axis=0)])
+               for k, v in q.items()}
+    ref = gus.neighbors(feats_p, 5)
+    np.testing.assert_array_equal(res.ids, ref.ids[:n])
+    np.testing.assert_array_equal(res.distances, ref.distances[:n])
+
+
+def test_missed_deadline_with_eligible_replica_still_hedges(world):
+    """With one eligible replica a missed deadline still reissues on it:
+    the replica's answer is served and no hedge is counted as skipped."""
+    ids, feats, cluster, scorer = world
+    primary, replica = _gus(scorer), _gus(scorer)
+    for g in (primary, replica):
+        _boot(g, ids, feats)
+    engine = GusEngine(primary, EngineConfig(hedge_ms=-1.0),
+                       replicas=[replica])
+    engine.obs.tracer.sample_every = 1
+    q = {k: v[40:41] for k, v in feats.items()}
+    res = engine.query(q, k=5)
+    assert engine.hedged == 1 and engine.replica_hedges == [1]
+    assert _skipped(engine) == 0
+    assert engine.obs.events.events("hedge_skipped") == []
+    hedge_span = engine.obs.tracer.finished[-1].find("answer_hedge")[0]
+    assert hedge_span.meta["member"] == "replica:0"
+    ref = replica.neighbors(q, 5)
+    np.testing.assert_array_equal(res.ids, ref.ids)
 
 
 # ------------------------------------------- sharded backend through engine
@@ -315,18 +377,31 @@ def test_query_path_spans_nest_under_the_answer(world, sharded_gus):
 
 
 def test_hedge_spans_match_the_hedge_counter(world, sharded_gus):
-    """With a zero deadline and no replica every answer is reissued on the
-    primary: one answer_hedge span per counted hedge."""
+    """With a zero deadline every answer misses it: one answer_hedge span
+    per counted hedge while a replica is eligible, and none, with a
+    skipped hedge counted instead, while no other member is."""
     ids, feats, cluster, scorer = world
-    engine = GusEngine(sharded_gus, EngineConfig(hedge_ms=0.0))
+    replica = _gus(scorer)
+    _boot(replica, ids, feats)
+    faults = FaultInjector()
+    engine = GusEngine(sharded_gus, EngineConfig(hedge_ms=0.0),
+                       replicas=[replica], faults=faults)
     engine.obs.tracer.sample_every = 1
     hedges = engine.obs.registry.get("engine_hedges_total")
-    before = hedges.value
+
+    def hedge_spans():
+        return sum(len(tr.find("answer_hedge"))
+                   for tr in engine.obs.tracer.finished)
+
     for lo in range(3):
         engine.query({k: v[lo:lo + 1] for k, v in feats.items()}, k=5)
-    spans = sum(len(tr.find("answer_hedge"))
-                for tr in engine.obs.tracer.finished)
-    assert spans == hedges.value - before == 3
+    assert hedge_spans() == hedges.value == 3
+    assert _skipped(engine) == 0
+    faults.partition(0)                    # no other eligible member
+    for lo in range(3):
+        engine.query({k: v[lo:lo + 1] for k, v in feats.items()}, k=5)
+    assert hedge_spans() == hedges.value == 3
+    assert _skipped(engine) == 3
     assert all(tr.problems() == [] for tr in engine.obs.tracer.finished)
 
 
@@ -551,10 +626,11 @@ def test_partitioned_replica_excluded_until_heal(world):
                             bootstrap_fraction=0.5)
     engine.submit_mutations(next(iter(stream)))
     engine.query(q, k=5)                   # hedge finds no eligible replica
-    assert engine.hedged == 1
+    assert engine.hedged == 0 and _skipped(engine) == 1
     assert engine.replica_hedges == [0]    # partitioned: stale, excluded
-    assert engine.primary.served == 1      # reissued against the primary
+    assert engine.primary.served == 1      # the primary's answer stands
     faults.heal(0)
     engine.query(q, k=5)                   # heal + catch-up: eligible again
     assert engine.replica_hedges == [1]
+    assert engine.hedged == 1 and _skipped(engine) == 1
     assert replica.applied_seq == engine.seq

@@ -89,11 +89,31 @@ from repro.ann.sparse import count_sketch
 from repro.core import hashing
 from repro.core.maintenance import MaintenanceConfig, resolve_legacy
 from repro.core.types import PAD_INDEX, SparseBatch
+from repro.kernels.topk_select import block_n_for
 from repro.launch.mesh import make_gus_mesh
 from repro.obs import Telemetry
 from repro.utils import pow2_pad
 
 _PAD_ID = 0xFFFFFFFF  # reserved: mutation-batch padding, never a point id
+
+
+def blocks_under_cursor(cursor: np.ndarray, slab: int, n_shards: int,
+                        block_n: int) -> float:
+    """Share of the ``block_n``-slot blocks of each shard's slabs, laid end
+    to end in partition order, that hold a slot under its partition's
+    append cursor. Every live slot lies under its cursor, so with every
+    partition probed, and slabs a whole number of blocks, this bounds the
+    share of blocks the fused shortlist kernel scores
+    (``kernels/ops.py::shortlist_block_live``)."""
+    fill = np.minimum(cursor, slab).reshape(n_shards, -1)
+    start = np.arange(fill.shape[1]) * slab
+    n_blocks = -(-fill.shape[1] * slab // block_n)
+    edges = np.zeros((n_shards, n_blocks + 1), np.int64)
+    shard, part = np.nonzero(fill)
+    np.add.at(edges, (shard, start[part] // block_n), 1)
+    np.add.at(edges, (shard, (start[part] + fill[shard, part] - 1)
+                      // block_n + 1), -1)
+    return float((np.cumsum(edges, axis=1)[:, :n_blocks] > 0).mean())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,6 +244,8 @@ class ShardedGusIndex:
         self.compact_s = 0.0                 # wall-clock spent compacting
         self.aged_out = 0                    # ids lost to ring wrap (0 when
         #                                      maintenance.compact is on)
+        self.live_block_share = 0.0          # blocks_under_cursor, kept
+        #                                      where cursors or slab move
         # standalone indexes get a private telemetry plane; an engine
         # rebinds its primary's index into the shared one (bind_telemetry)
         self.obs = Telemetry()
@@ -249,6 +271,10 @@ class ShardedGusIndex:
             "index_compact_ms", "wall-clock per compaction")
         self._h_search = reg.histogram(
             "index_search_ms", "device fan-out/merge time per search call")
+        self._g_live_blocks = reg.gauge(
+            "index_shortlist_live_block_share",
+            "share of the shortlist kernel's slab blocks under a cursor")
+        self._g_live_blocks.set(self.live_block_share)
         # carry lifetime counts already accumulated into the new registry
         self._c_compactions.inc(self.compactions)
         self._c_reclaimed.inc(self.reclaimed)
@@ -376,6 +402,18 @@ class ShardedGusIndex:
         self._mutate = jax.jit(make_mutate_step(self.mesh, cell, self.salt))
         self._tombstone = jax.jit(make_delete_step(self.mesh, cell))
         self._compact_step = jax.jit(make_compact_step(self.mesh, cell))
+        self._note_cursors()
+
+    def _note_cursors(self) -> None:
+        """Refresh ``live_block_share`` after the cursors or the slab
+        moved, at the kernel's block width with every partition probed."""
+        cfg = self.cfg
+        cell = self._cell()
+        n = cfg.n_partitions // cfg.n_shards * self.slab
+        bn = block_n_for(n, min(cell.reorder or 2 * cell.top_k, n))
+        self.live_block_share = blocks_under_cursor(
+            self._cursor, self.slab, cfg.n_shards, bn)
+        self._g_live_blocks.set(self.live_block_share)
 
     # ------------------------------------------------------------ mutations
 
@@ -505,6 +543,7 @@ class ShardedGusIndex:
                     new_codes2=b_codes2)
             self._cursor += inc
             pending.append((n_c, chunk_ids, r_part, r_pos, inc))
+        self._note_cursors()
         return pending
 
     def _materialize(self, pending) -> None:
@@ -545,6 +584,7 @@ class ShardedGusIndex:
         stale = [r for r in set(stale) if self.id_of_row[r] < 0]
         if stale:
             self._tombstone_rows(stale)
+        self._note_cursors()
 
     def finish_upsert(self, pending) -> None:
         """Barrier: materialize landing sites, mirror them into the host
@@ -638,6 +678,7 @@ class ShardedGusIndex:
         self.id_of_row = new_id_of_row
         self._cursor = live.astype(np.int64)
         self.version += 1
+        self._note_cursors()
         n_live = int(live.sum())
         reclaimed = max(occupied - n_live, 0)
         dt = time.perf_counter() - t0
@@ -695,6 +736,7 @@ class ShardedGusIndex:
         self._mutate = jax.jit(make_mutate_step(self.mesh, cell, self.salt))
         self._tombstone = jax.jit(make_delete_step(self.mesh, cell))
         self._compact_step = jax.jit(make_compact_step(self.mesh, cell))
+        self._note_cursors()
         self.slab_grows += 1
         self._c_slab_grows.inc()
         self.obs.events.emit("slab_grow", slab=int(self.slab))
@@ -856,6 +898,7 @@ class ShardedGusIndex:
             "slab_grows": self.slab_grows,
             "resplits": self.resplits,
             "aged_out": self.aged_out,
+            "live_block_share": self.live_block_share,
             "version": self.version,
         }
 

@@ -29,6 +29,18 @@ id-sorted order; both keep exactly one copy per id and copies share exact
 scores, so final (id, distance) results are unchanged — only the internal
 tie-break moved, and it is documented here and in docs/ARCHITECTURE.md.
 
+Work follows ``valid``: ``ops.shortlist_block_live`` flags each (8-row,
+``BLOCK_N``-lane) grid block that holds a valid slot, the flags ride in
+as scalar prefetch, and a step scores and merges only when its block is
+flagged or is block 0; the dedup runs at the last step regardless. This
+is exact: ``BLOCK_N >= k``, so block 0 alone fills the running top-k, and
+a skipped block holds only -inf at indices above every kept entry, which
+the lowest-index tie-break never lets in (rows with fewer than k valid
+candidates included). Served slabs fill from the front, so every block
+past a partition's append cursor is skipped. A row block scores its
+first ``min(B, 8)`` rows, so a batch under 8 scores only its real rows;
+padded rows stay -inf through ``valid = 0``.
+
 Ordered accumulation: f32 addition is not associative, so the kernel, the
 single-jit XLA twin (``fused_query_xla``) and the oracle
 (``ref.fused_query_ref``) all accumulate subspaces left-to-right
@@ -83,8 +95,8 @@ def _dedup_cut(vals, ids, ok):
     return jnp.where(dup != 0, NEG_INF, vals)
 
 
-def _fused_kernel(*refs, n_centers: int, k: int, block_n: int,
-                  quantized: bool):
+def _fused_kernel(block_live_ref, *refs, n_centers: int, k: int,
+                  block_n: int, live_rows: int, quantized: bool):
     if quantized:
         (lut_ref, scale_ref, codes_ref, ids_ref, valid_ref, bias_ref,
          vals_ref, idxs_ref, sel_ids, sel_ok) = refs
@@ -92,7 +104,11 @@ def _fused_kernel(*refs, n_centers: int, k: int, block_n: int,
         (lut_ref, codes_ref, ids_ref, valid_ref, bias_ref,
          vals_ref, idxs_ref, sel_ids, sel_ok) = refs
         scale_ref = None
-    j = pl.program_id(1)
+    i, j, n_j = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    # grid positions are read outside the branches below: the interpreter
+    # cannot lower program_id inside a pl.when
+    live = block_live_ref[i * n_j + j]
+    idx = block_index(valid_ref.shape, block_n)
 
     @pl.when(j == 0)
     def _():
@@ -101,22 +117,27 @@ def _fused_kernel(*refs, n_centers: int, k: int, block_n: int,
         sel_ids[...] = jnp.zeros(sel_ids.shape, jnp.int32)
         sel_ok[...] = jnp.zeros(sel_ok.shape, jnp.int32)
 
-    valid = valid_ref[...]
-    acc = score_block(lut_ref, codes_ref, n_centers=n_centers,
-                      shared_codes=False, scale_ref=scale_ref)
-    scores = jnp.where(valid != 0, acc + bias_ref[...], NEG_INF)
-    vals, idxs, (ids, ok) = select_topk(
-        [(vals_ref[...], idxs_ref[...], (sel_ids[...], sel_ok[...])),
-         (scores, block_index(scores.shape, block_n),
-          (ids_ref[...], valid))], k)
-    vals_ref[...] = vals
-    idxs_ref[...] = idxs
-    sel_ids[...] = ids
-    sel_ok[...] = ok
-
-    @pl.when(j == pl.num_programs(1) - 1)
+    # block 0 fills the running top-k (block_n >= k); a later block with
+    # no valid slot holds only -inf at higher indices, which can never
+    # displace an entry, so skipping it leaves the result bit for bit
+    @pl.when((j == 0) | (live != 0))
     def _():
-        vals_ref[...] = _dedup_cut(vals, ids, ok)
+        valid = valid_ref[...]
+        acc = score_block(lut_ref, codes_ref, n_centers=n_centers,
+                          shared_codes=False, scale_ref=scale_ref,
+                          live_rows=live_rows)
+        scores = jnp.where(valid != 0, acc + bias_ref[...], NEG_INF)
+        vals, idxs, (ids, ok) = select_topk(
+            [(vals_ref[...], idxs_ref[...], (sel_ids[...], sel_ok[...])),
+             (scores, idx, (ids_ref[...], valid))], k)
+        vals_ref[...] = vals
+        idxs_ref[...] = idxs
+        sel_ids[...] = ids
+        sel_ok[...] = ok
+
+    @pl.when(j == n_j - 1)
+    def _():
+        vals_ref[...] = _dedup_cut(vals_ref[...], sel_ids[...], sel_ok[...])
 
 
 # ---------------------------------------------------------------------------
@@ -139,61 +160,81 @@ def quantize_lut(lut: jax.Array):
 # pallas_call wrappers
 
 
-def _fused_call(lut, scale, codes, ids, valid, bias, k: int,
+def block_grid(b: int, n: int, k: int) -> tuple[int, int, int]:
+    """(padded rows, padded candidates, lanes per block) of the kernel's
+    grid over ``b`` query rows x ``n`` candidates at top-``k``."""
+    bn = block_n_for(n, k)
+    return round_up(b, BLOCK_B), round_up(n, bn), bn
+
+
+def _fused_call(lut, scale, codes, ids, valid, bias, block_live, k: int,
                 interpret: bool):
     """lut [B, M, C] (i8 when ``scale`` f32 [B, M] is given); codes u8
-    [B, N, M]; ids i32, valid i32, bias f32 [B, N]. B pads to 8 and N to
-    the block; padded candidates sit after the real ones (valid=0, id=-1)
-    so the lowest-index tie-break never prefers them while k <= n."""
+    [B, N, M]; ids i32, valid i32, bias f32 [B, N]; block_live i32
+    [Bp/8 * Np/bn], row-block major, non-zero where a block may hold a
+    valid slot (None: every block). B pads to 8 and N to the block;
+    padded candidates sit after the real ones (valid=0, id=-1) so the
+    lowest-index tie-break never prefers them while k <= n. A row block
+    scores its first ``min(B, 8)`` rows: the padding of a batch under 8
+    stays -inf unscored."""
     b, m, c = lut.shape
     n = codes.shape[1]
     assert k <= n, f"k={k} exceeds candidate count n={n}"
-    bn = block_n_for(n, k)
-    bp, np_ = round_up(b, BLOCK_B), round_up(n, bn)
+    bp, np_, bn = block_grid(b, n, k)
+    grid = (bp // BLOCK_B, np_ // bn)
+    if block_live is None:
+        block_live = jnp.ones((grid[0] * grid[1],), jnp.int32)
 
     def rows(x, value=0):
         return pad_axis(pad_axis(x, 0, bp), 1, np_, value)
 
     args = [pad_axis(lut, 0, bp).transpose(1, 0, 2)]          # [M, Bp, C]
-    specs = [pl.BlockSpec((m, BLOCK_B, c), lambda i, j: (0, i, 0))]
+    specs = [pl.BlockSpec((m, BLOCK_B, c), lambda i, j, _: (0, i, 0))]
     if scale is not None:
         args.append(pad_axis(scale, 0, bp, 1.0).T[:, :, None])  # [M, Bp, 1]
-        specs.append(pl.BlockSpec((m, BLOCK_B, 1), lambda i, j: (0, i, 0)))
-    row_spec = pl.BlockSpec((BLOCK_B, bn), lambda i, j: (i, j))
+        specs.append(pl.BlockSpec((m, BLOCK_B, 1),
+                                  lambda i, j, _: (0, i, 0)))
+    row_spec = pl.BlockSpec((BLOCK_B, bn), lambda i, j, _: (i, j))
     args += [rows(codes).transpose(0, 2, 1),                   # [Bp, M, Np]
              rows(ids, -1), rows(valid), rows(bias)]
-    specs += [pl.BlockSpec((BLOCK_B, m, bn), lambda i, j: (i, 0, j)),
+    specs += [pl.BlockSpec((BLOCK_B, m, bn), lambda i, j, _: (i, 0, j)),
               row_spec, row_spec, row_spec]
-    out_spec = pl.BlockSpec((BLOCK_B, k), lambda i, j: (i, 0))
+    out_spec = pl.BlockSpec((BLOCK_B, k), lambda i, j, _: (i, 0))
     vals, idxs = pl.pallas_call(
         functools.partial(_fused_kernel, n_centers=c, k=k, block_n=bn,
+                          live_rows=min(b, BLOCK_B),
                           quantized=scale is not None),
-        grid=(bp // BLOCK_B, np_ // bn),
-        in_specs=specs,
-        out_specs=(out_spec, out_spec),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=specs,
+            out_specs=(out_spec, out_spec),
+            scratch_shapes=[pltpu.VMEM((BLOCK_B, k), jnp.int32),
+                            pltpu.VMEM((BLOCK_B, k), jnp.int32)]),
         out_shape=(jax.ShapeDtypeStruct((bp, k), jnp.float32),
                    jax.ShapeDtypeStruct((bp, k), jnp.int32)),
-        scratch_shapes=[pltpu.VMEM((BLOCK_B, k), jnp.int32),
-                        pltpu.VMEM((BLOCK_B, k), jnp.int32)],
         compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(*args)
+    )(block_live, *args)
     return vals[:b], idxs[:b]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def fused_query_kernel(lut, codes, ids, valid, bias, k: int, *,
-                       interpret: bool = False):
+def fused_query_kernel(lut, codes, ids, valid, bias, k: int,
+                       block_live=None, *, interpret: bool = False):
     """Single pallas_call: lut f32 [B,M,C]; codes u8 [B,N,M]; ids i32 [B,N];
-    valid i32 [B,N]; bias f32 [B,N] -> (vals f32 [B,k], idxs i32 [B,k])."""
-    return _fused_call(lut, None, codes, ids, valid, bias, k, interpret)
+    valid i32 [B,N]; bias f32 [B,N]; optional block flags (``_fused_call``)
+    -> (vals f32 [B,k], idxs i32 [B,k])."""
+    return _fused_call(lut, None, codes, ids, valid, bias, block_live, k,
+                       interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def fused_query_kernel_int8(qlut, scale, codes, ids, valid, bias, k: int, *,
-                            interpret: bool = False):
+def fused_query_kernel_int8(qlut, scale, codes, ids, valid, bias, k: int,
+                            block_live=None, *, interpret: bool = False):
     """Quantised variant: qlut i8 [B,M,C]; scale f32 [B,M]; rest as above."""
-    return _fused_call(qlut, scale, codes, ids, valid, bias, k, interpret)
+    return _fused_call(qlut, scale, codes, ids, valid, bias, block_live, k,
+                       interpret)
 
 
 # ---------------------------------------------------------------------------

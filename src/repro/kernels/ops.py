@@ -11,6 +11,8 @@ tests only. The kernel wrappers pad to the hardware grain (8 query rows,
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -136,13 +138,31 @@ def pq_score_dedup_topk(lut, codes, ids, k: int, *, valid=None, bias=None,
     bias = (jnp.zeros((b, n), jnp.float32) if bias is None
             else jnp.asarray(bias).astype(jnp.float32))
     interpret = _interpret(interpret)
+    # the flags are their own program, outside the kernel's jit: the trace
+    # reader counts every op named after the kernel as one of its calls
+    block_live = shortlist_block_live(valid, k)
     valid_i = valid.astype(jnp.int32)
     if quantized:
         qlut, scale = _fq.quantize_lut(lut)
         return _fq.fused_query_kernel_int8(qlut, scale, codes, ids, valid_i,
-                                           bias, k, interpret=interpret)
+                                           bias, k, block_live,
+                                           interpret=interpret)
     return _fq.fused_query_kernel(lut, codes, ids, valid_i, bias, k,
-                                  interpret=interpret)
+                                  block_live, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames="k")
+def shortlist_block_live(valid, k: int) -> jax.Array:
+    """The fused shortlist kernel's block flags: 1 for each (8-row,
+    block-lane) block of its grid that holds a valid slot, which are the
+    blocks it scores (block 0 always runs). valid bool [B, N] -> i32
+    [Bp/8 * Np/bn], row-block major."""
+    b, n = valid.shape
+    bp, np_, bn = _fq.block_grid(b, n, k)
+    v = _pq.pad_axis(_pq.pad_axis(valid, 0, bp), 1, np_)
+    live = jnp.any(v.reshape(bp // _pq.BLOCK_B, _pq.BLOCK_B, np_ // bn, bn),
+                   axis=(1, 3))
+    return live.astype(jnp.int32).reshape(-1)
 
 
 def scorer_mlp(feats, params: dict, *, interpret: bool | None = None):

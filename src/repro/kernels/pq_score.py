@@ -73,13 +73,15 @@ def split_bf16(x):
 
 
 def score_block(lut_ref, codes_ref, *, n_centers: int, shared_codes: bool,
-                scale_ref=None):
+                scale_ref=None, live_rows: int | None = None):
     """Ordered one-hot-matmul LUT scores of one grid step -> f32 [rows, bn].
 
     lut_ref [M, rows, C] f32 — or i8 with ``scale_ref`` f32 [M, rows, 1],
     dequantised per subspace before scoring; codes_ref [rows, M, bn], or
     [M, bn] with ``shared_codes``. Row r accumulates m = 0..M-1 left to
-    right: bitwise ``acc += lut[r, m, codes[r, :, m]]``."""
+    right: bitwise ``acc += lut[r, m, codes[r, :, m]]``. Per-row codes
+    score only the first ``live_rows`` rows (default all); the rest stay
+    0."""
     n_sub, rows, _ = lut_ref.shape
     bn = codes_ref.shape[-1]
     centers = jax.lax.broadcasted_iota(jnp.int32, (n_centers, bn), 0)
@@ -111,7 +113,8 @@ def score_block(lut_ref, codes_ref, *, n_centers: int, shared_codes: bool,
             acc = jnp.where(row == r, acc + part, acc)
         return acc
 
-    return jax.lax.fori_loop(0, rows, per_row, acc)
+    return jax.lax.fori_loop(0, rows if live_rows is None else live_rows,
+                             per_row, acc)
 
 
 def _pq_score_kernel(lut_ref, codes_ref, out_ref, *, n_centers: int,

@@ -7,6 +7,8 @@ indices including tie-break order — across randomized shapes, duplicate
 SOAR copies, all-tombstone rows, score ties, and k >= live-rows edges.
 This is the pin that lets the serving path default to the fused op.
 """
+import functools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -14,6 +16,7 @@ import pytest
 from _hypo_compat import given, settings, st
 from repro.ann.scann import ScannConfig, ScannIndex
 from repro.core.types import SparseBatch
+from repro.kernels import fused_query as fq
 from repro.kernels import ops, ref
 
 
@@ -232,3 +235,92 @@ def test_fused_k_equals_n_full_permutation(quantized):
                                       quantized=quantized)
     for row in np.asarray(idxs):
         assert sorted(row.tolist()) == list(range(n))
+
+
+# ----------------------------------------------------------- block flags
+# The kernel scores and merges only the 1,024-lane blocks that hold a
+# valid slot (and block 0). Slabs fill from the front, so the served
+# masks have empty blocks behind every partition's cursor; each case
+# mixes such patterns and a real row count (rows pad to 8 in the kernel).
+
+def _block_mask(case, rng):
+    """(rows, n, valid bool [rows, n]) of one flag-parity case."""
+    def live(rows, n, lo, hi, share=0.5):
+        v = np.zeros((rows, n), bool)
+        v[:, lo:hi] = rng.random((rows, hi - lo)) < share
+        return v
+
+    if case == "block0_only":
+        return 1, 4096, live(1, 4096, 0, 300)
+    if case == "empty_middle":
+        v = live(2, 4096, 0, 1024) | live(2, 4096, 3072, 4096)
+        return 2, 4096, v
+    if case == "last_block_only":           # last block partly padding
+        return 4, 3500, live(4, 3500, 3072, 3500)
+    if case == "few_valid_row":             # row 5: 5 valid, k is 16
+        v = live(8, 4000, 0, 700) | live(8, 4000, 2048, 2600)
+        v[5] = False
+        v[5, rng.choice(np.arange(2048, 3072), 5, replace=False)] = True
+        return 8, 4000, v
+    if case == "invalid_row":
+        v = live(8, 4096, 0, 200) | live(8, 4096, 3500, 4096, 0.1)
+        v[3] = False
+        return 8, 4096, v
+    if case == "two_row_blocks":            # different flags per 8 rows
+        v = live(16, 4096, 0, 1024) | live(16, 4096, 2048, 2100)
+        v[8:] = live(8, 4096, 3072, 4096, 0.2)
+        v[12] = False
+        return 16, 4096, v
+    raise ValueError(case)
+
+
+BLOCK_CASES = ["block0_only", "empty_middle", "last_block_only",
+               "few_valid_row", "invalid_row", "two_row_blocks"]
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("case", BLOCK_CASES)
+def test_fused_block_flags_match_ref(case, quantized):
+    """The kernel with its block flags equals the oracle bit for bit."""
+    rng = np.random.default_rng(BLOCK_CASES.index(case))
+    b, n, valid_np = _block_mask(case, rng)
+    m, c, k = 3, 8, 16
+    lut = jnp.asarray(rng.choice(np.asarray([-1.5, -0.25, 0.0, 0.5, 2.0],
+                                            np.float32), size=(b, m, c)))
+    codes = jnp.asarray(rng.integers(0, c, (b, n, m)), jnp.uint8)
+    ids = jnp.asarray(rng.integers(0, 400, (b, n)), jnp.int32)
+    bias = jnp.asarray(rng.choice(np.asarray([0.0, 0.75], np.float32),
+                                  size=(b, n)))
+    valid = jnp.asarray(valid_np)
+    want = ref.fused_query_ref(lut, codes, ids, k, valid=valid, bias=bias,
+                               quantized=quantized)
+    got = ops.pq_score_dedup_topk(lut, codes, ids, k, valid=valid,
+                                  bias=bias, quantized=quantized,
+                                  use_kernel=True)
+    _check(got, want)
+    flags = np.asarray(ops.shortlist_block_live(valid, k))
+    blocks = -(-n // 1024)
+    want_flags = [valid_np[r:r + 8, j * 1024:(j + 1) * 1024].any()
+                  for r in range(0, -(-b // 8) * 8, 8)
+                  for j in range(blocks)]
+    np.testing.assert_array_equal(flags, np.asarray(want_flags, np.int32))
+
+
+def test_fused_block_flags_none_is_all_live():
+    """``block_live=None`` scores every block, like all-ones flags; flags
+    that leave a live block out drop its candidates (they are obeyed)."""
+    rng = np.random.default_rng(21)
+    b, n, m, c, k = 3, 3072, 2, 8, 8
+    lut = jnp.asarray(rng.normal(size=(b, m, c)), jnp.float32)
+    codes = jnp.asarray(rng.integers(0, c, (b, n, m)), jnp.uint8)
+    ids = jnp.asarray(rng.integers(0, 50, (b, n)), jnp.int32)
+    valid_np = rng.random((b, n)) < 0.3
+    valid = jnp.asarray(valid_np, jnp.int32)
+    bias = jnp.zeros((b, n), jnp.float32)
+    run = functools.partial(fq.fused_query_kernel, lut, codes, ids, valid,
+                            bias, k, interpret=True)
+    _check(run(None), run(jnp.ones((3,), jnp.int32)))
+    only0 = np.where(np.arange(n) < 1024, valid_np, False)
+    want = ref.fused_query_ref(lut, codes, ids, k, valid=jnp.asarray(only0),
+                               bias=bias)
+    _check(run(jnp.zeros((3,), jnp.int32)), want)

@@ -25,11 +25,13 @@ import numpy as np
 import pytest
 
 from repro.ann.brute import BruteIndex
-from repro.ann.sharded_index import ShardedConfig, ShardedGusIndex
+from repro.ann.sharded_index import (ShardedConfig, ShardedGusIndex,
+                                     blocks_under_cursor)
 from repro.core import BucketConfig
 from repro.core.embedding import EmbeddingGenerator
 from repro.core.maintenance import MaintenanceConfig
 from repro.data.synthetic import OGB_ARXIV_LIKE, make_dataset
+from repro.kernels import ops
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -219,6 +221,55 @@ def test_wrap_churn_without_auto_compact_ages_out(corpus):
     occ = idx.occupancy()
     assert occ["aged_out"] > 0
     assert len(idx.row_of) < len(live)
+
+
+# -------------------------------------------------- kernel block occupancy
+
+
+def test_live_block_share_follows_cursors(corpus):
+    """``live_block_share`` (and its gauge) is the share of the shortlist
+    kernel's block flags over the slabs with every partition probed: it
+    follows an upsert and a compaction, and between a delete and the
+    compaction it bounds the flags from above. Two partitions, so each
+    point puts one SOAR copy in each: 600 copies per slab, 1,100 after
+    500 inserts, 500 after 600 deletes and a compaction."""
+    ids, emb, gen = corpus
+    idx = ShardedGusIndex(gen.k_max, ShardedConfig(
+        **{**BASE, "n_partitions": 2, "reorder": 64}))
+    idx.build(ids, emb)
+    gauge = idx.obs.registry.get("index_shortlist_live_block_share")
+
+    def flag_share():
+        valid = np.asarray(idx.state["valid"]).reshape(1, -1)
+        return float(np.mean(np.asarray(ops.shortlist_block_live(
+            valid, min(64, valid.shape[1])))))
+
+    def share():
+        got = idx.occupancy()["live_block_share"]
+        assert gauge.value == got
+        return got
+
+    assert idx.slab == 8192                  # 16 blocks of 1,024 slots
+    assert share() == flag_share() == 2 / 16
+    idx.upsert(np.arange(10_000, 10_500), emb[:500])
+    assert share() == flag_share() == 4 / 16
+    idx.delete(ids)
+    assert share() == 4 / 16 >= flag_share()
+    idx.compact()
+    assert share() == flag_share() == 2 / 16
+
+
+@pytest.mark.parametrize("cursor,slab,shards,block_n,want", [
+    ([0, 0, 0, 0], 256, 1, 1024, 0.0),
+    ([0, 5, 0, 300], 512, 1, 1024, 1.0),        # partitions share blocks
+    ([1536, 0], 1536, 1, 1024, 2 / 3),          # a slab straddles a block
+    ([1536, 1], 1536, 1, 1024, 2 / 3),
+    ([5, 0, 0, 0], 1024, 2, 1024, 0.25),         # shards lay out apart
+    ([600, 1100], 8192, 1, 1024, 3 / 16),
+])
+def test_blocks_under_cursor_layouts(cursor, slab, shards, block_n, want):
+    got = blocks_under_cursor(np.asarray(cursor), slab, shards, block_n)
+    assert got == pytest.approx(want)
 
 
 # --------------------------------------------------------- skew re-split

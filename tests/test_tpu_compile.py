@@ -25,6 +25,8 @@ from repro.kernels import fused_query, ops, pq_score, scorer_mlp, sparse_dot
 from repro.kernels import topk_select
 
 B, M, C, N, K = 64, 16, 256, 2048, 40      # query batch, PQ, candidates, k
+# the products cells' query step: 156 probed slabs of 4,096 slots, PQ 8
+CELL_B, CELL_M, CELL_N, CELL_K = 8, 8, 156 * 4096, 128
 KD, F, H = 16, 16, 128                     # sparse nnz, scorer widths
 
 
@@ -74,6 +76,13 @@ def _kernel_cases():
                 q, s, c, i, v, b, K),
             [((B, M, C), jnp.int8), ((B, M), f32), ((B, N, M), u8),
              (row, i32), (row, i32), (row, f32)]),
+        "fused_query_cell": (
+            lambda lut, c, i, v, b: fused_query.fused_query_kernel(
+                lut, c, i, v, b, CELL_K,
+                ops.shortlist_block_live(v != 0, CELL_K)),
+            [((CELL_B, CELL_M, C), f32), ((CELL_B, CELL_N, CELL_M), u8),
+             ((CELL_B, CELL_N), i32), ((CELL_B, CELL_N), i32),
+             ((CELL_B, CELL_N), f32)]),
         "topk_select": (lambda s: topk_select.topk_select(s, K),
                         [(row, f32)]),
         "sparse_dot": (
